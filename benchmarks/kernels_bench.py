@@ -1,9 +1,8 @@
 """E8 — kernel microbenchmarks.
 
-On this CPU container the Pallas kernels run in interpret mode (correctness
-only — their wall time is meaningless), so the timings reported here are the
-XLA reference paths; the kernels are asserted allclose against the oracles at
-benchmark shapes.  On TPU the same harness times the Mosaic kernels.
+The timings reported here are the XLA reference paths; the Pallas kernels
+run in interpret mode (correctness only — their wall time is meaningless)
+and are asserted allclose against the oracles at benchmark shapes.
 """
 from __future__ import annotations
 
@@ -39,7 +38,8 @@ def run():
     labels = jax.random.randint(jax.random.fold_in(KEY, 2), (N,), 0, V)
     ref = lambda *a: _XENT_REF(*a, vocab_size=V)
     us = timeit(ref, h, w, labels, iters=3)
-    kern = fused_xent(h[:256], w, labels[:256], vocab_size=V, bn=128, bv=512)
+    kern = fused_xent(h[:256], w, labels[:256], vocab_size=V, bn=128, bv=512,
+                      interpret=True)
     np.testing.assert_allclose(kern, xent_ref(h[:256], w, labels[:256],
                                               vocab_size=V), rtol=1e-3, atol=1e-3)
     emit("kernel_fused_xent", us, shape=f"{N}x{d}x{V}",
@@ -54,7 +54,7 @@ def run():
     ref = lambda *a: _ATTN_REF(*a, causal=True)
     us = timeit(ref, q, k, v, iters=3)
     kern = flash_attention(q[:2, :256], k[:2, :256], v[:2, :256],
-                           causal=True, bq=128, bk=128)
+                           causal=True, bq=128, bk=128, interpret=True)
     np.testing.assert_allclose(
         kern, attention_ref(q[:2, :256], k[:2, :256], v[:2, :256],
                             causal=True), rtol=2e-5, atol=2e-5)
@@ -72,7 +72,7 @@ def run():
     ref = lambda *a: _SSD_REF(*a, chunk=128)
     us = timeit(ref, x, dt, A, B, C, iters=3)
     y1, s1 = ssd_chunked_pallas(x[:1, :128], dt[:1, :128], A, B[:1, :128],
-                                C[:1, :128], chunk=64)
+                                C[:1, :128], chunk=64, interpret=True)
     y2, s2 = ssd_ref(x[:1, :128], dt[:1, :128], A, B[:1, :128], C[:1, :128],
                      chunk=64)
     np.testing.assert_allclose(y1, y2, rtol=1e-3, atol=1e-3)

@@ -180,10 +180,10 @@ class DeviceRing:
                 k: jax.device_put(_shard_layout(np.asarray(v),
                                                 self.n_batches, n_dev), sh)
                 for k, v in epoch_arrays.items()}
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         sliced = shard_map(self._slice_local, mesh=mesh,
                            in_specs=(spec, P()), out_specs=spec,
-                           check_rep=False)
+                           check_vma=False)
         self._slice = jax.jit(sliced)
 
     # -- slicing --------------------------------------------------------

@@ -80,7 +80,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -203,7 +203,7 @@ class MeshStrategy:
         return shard_map(fn, mesh=self.mesh,
                          in_specs=(P(), P(), P(self.axis), P()),
                          out_specs=(P(), P(), P()),
-                         check_rep=False)
+                         check_vma=False)
 
     def wrap_sched(self, fn: Callable) -> Callable:
         """Scheduled twin of ``wrap_step`` for the 5-ary bodies from
@@ -218,7 +218,7 @@ class MeshStrategy:
         return shard_map(fn, mesh=self.mesh,
                          in_specs=(P(), P(), P(), P(self.axis), P()),
                          out_specs=(P(), P(), P(), P()),
-                         check_rep=False)
+                         check_vma=False)
 
     def constrain_batch(self, batch):
         """Pin every divisible batch leaf's leading dim to the data axes —

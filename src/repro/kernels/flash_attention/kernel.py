@@ -68,10 +68,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
-                    bq: int = 128, bk: int = 128, interpret: bool = True):
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    window: int | None = None, bq: int = 128, bk: int = 128):
     """q: (BH, Sq, hd); k/v: (BH, Sk, hd) — heads pre-flattened (GQA mapping
-    done by the caller in ops.py).  Returns (BH, Sq, hd) in q.dtype."""
+    done by the caller in ops.py).  Returns (BH, Sq, hd) in q.dtype.
+    ``interpret`` runs the Pallas interpreter instead of Mosaic."""
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
     # requested tiles are upper bounds (see kernels/tiling.py): model seq

@@ -21,21 +21,17 @@ from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, window, bq, bk):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, window, bq, bk, interpret):
     return flash_attention(q, k, v, causal=causal, window=window,
-                           bq=bq, bk=bk, interpret=_use_interpret())
+                           bq=bq, bk=bk, interpret=interpret)
 
 
-def _flash_fwd(q, k, v, causal, window, bq, bk):
-    return _flash(q, k, v, causal, window, bq, bk), (q, k, v)
+def _flash_fwd(q, k, v, causal, window, bq, bk, interpret):
+    return _flash(q, k, v, causal, window, bq, bk, interpret), (q, k, v)
 
 
-def _flash_bwd(causal, window, bq, bk, res, g):
+def _flash_bwd(causal, window, bq, bk, interpret, res, g):
     q, k, v = res
     _, vjp = jax.vjp(
         lambda q_, k_, v_: attention_ref(q_, k_, v_, causal=causal,
@@ -46,15 +42,18 @@ def _flash_bwd(causal, window, bq, bk, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def gqa_flash(q, k, v, *, causal=True, window=None, bq=128, bk=128):
-    """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) -> (B, Sq, H, hd)."""
+def gqa_flash(q, k, v, *, interpret: bool, causal=True, window=None, bq=128,
+              bk=128):
+    """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) -> (B, Sq, H, hd).
+    ``interpret`` runs the kernel through the Pallas interpreter instead of
+    Mosaic."""
     B, Sq, H, hd = q.shape
     K = k.shape[2]
     rep = H // K
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
     kf = jnp.repeat(k.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, -1, hd)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, -1, hd)
-    of = _flash(qf, kf, vf, causal, window, bq, bk)
+    of = _flash(qf, kf, vf, causal, window, bq, bk, interpret)
     return of.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
 

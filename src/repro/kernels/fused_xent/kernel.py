@@ -9,7 +9,10 @@ gemma3's 262k vocab the naive path writes B·S·V logits to HBM twice.
 
 Tiling: token tile ``bn`` × vocab tile ``bv`` (both 128-aligned for the MXU);
 the h tile (bn, d) stays resident in VMEM across the vocab sweep
-(index_map ignores the vocab grid coordinate).
+(index_map ignores the vocab grid coordinate).  Per-token vectors (labels,
+the nll output and the running max/sumexp/gold) are ``(bn, 1)`` columns:
+Mosaic tiles the last two dims of every block, and a 1-D ``(bn,)`` block
+gets an HBM layout that does not match the kernel's.
 """
 from __future__ import annotations
 
@@ -42,15 +45,16 @@ def _xent_kernel(h_ref, w_ref, label_ref, out_ref, m_ref, s_ref, g_ref,
     col = v0 + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col < vocab_size, logits, -1e30)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1))
+    m_prev = m_ref[...]                            # (bn, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
     corr = jnp.exp(m_prev - m_new)
-    s_ref[...] = s_ref[...] * corr + jnp.sum(jnp.exp(logits - m_new[:, None]), axis=1)
+    s_ref[...] = s_ref[...] * corr + jnp.sum(jnp.exp(logits - m_new), axis=1,
+                                             keepdims=True)
     m_ref[...] = m_new
 
-    labels = label_ref[...]                        # (bn,)
-    hit = col == labels[:, None]
-    g_ref[...] = g_ref[...] + jnp.sum(jnp.where(hit, logits, 0.0), axis=1)
+    hit = col == label_ref[...]                    # (bn, bv) vs (bn, 1)
+    g_ref[...] = g_ref[...] + jnp.sum(jnp.where(hit, logits, 0.0), axis=1,
+                                      keepdims=True)
 
     @pl.when(vi == nv - 1)
     def _finish():
@@ -58,9 +62,10 @@ def _xent_kernel(h_ref, w_ref, label_ref, out_ref, m_ref, s_ref, g_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("vocab_size", "bn", "bv", "interpret"))
-def fused_xent(h, w, labels, *, vocab_size: int, bn: int = 256, bv: int = 512,
-               interpret: bool = True):
-    """h: (N, d); w: (d, Vp); labels: (N,) -> nll (N,) f32."""
+def fused_xent(h, w, labels, *, vocab_size: int, interpret: bool, bn: int = 256,
+               bv: int = 512):
+    """h: (N, d); w: (d, Vp); labels: (N,) -> nll (N,) f32.  ``interpret``
+    runs the kernel through the Pallas interpreter instead of Mosaic."""
     N, d = h.shape
     Vp = w.shape[1]
     # requested tiles are upper bounds: training bodies hand us whatever
@@ -68,20 +73,18 @@ def fused_xent(h, w, labels, *, vocab_size: int, bn: int = 256, bv: int = 512,
     bn = divisor_tile(N, bn)
     bv = divisor_tile(Vp, bv)
     grid = (N // bn, Vp // bv)
-    return pl.pallas_call(
+    col = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
+    nll = pl.pallas_call(
         functools.partial(_xent_kernel, bv=bv, vocab_size=vocab_size),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((d, bv), lambda i, j: (0, j)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
+            col,
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((N,), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
-        ],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((N, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bn, 1), jnp.float32)] * 3,
         interpret=interpret,
-    )(h, w, labels)
+    )(h, w, labels.reshape(N, 1).astype(jnp.int32))
+    return nll.reshape(N)

@@ -1,7 +1,8 @@
 """Jit'd wrapper: model-facing fused cross-entropy.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU it lowers
-to Mosaic.  ``fused_xent_sum`` is the surface ``lm_loss_fn`` consumes.
+``fused_xent_sum`` is the surface ``lm_loss_fn`` consumes.  The kernel
+lowers to Mosaic unless the caller asks for ``interpret=True`` (the
+``--kernels interpret`` correctness harness).
 """
 from __future__ import annotations
 
@@ -14,36 +15,32 @@ from repro.kernels.fused_xent.kernel import fused_xent
 from repro.kernels.fused_xent.ref import xent_ref
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@partial(jax.custom_vjp, nondiff_argnums=(4,))
-def fused_xent_sum(h, w, labels, mask, vocab_size: int):
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def fused_xent_sum(h, w, labels, mask, vocab_size: int, interpret: bool):
     """h: (B,S,d); w: (d,Vp); labels/mask: (B,S) -> (sum_nll, sum_mask).
 
     Forward runs the Pallas streaming kernel; backward uses the analytic
     softmax gradient (p − onehot) computed in sequence chunks (a bwd kernel
     is the TPU follow-up; the fwd kernel is the ISGD hot path since the
     controller and the Alg.2 early-stop check only need ψ)."""
-    return _fwd_value(h, w, labels, mask, vocab_size)
+    return _fwd_value(h, w, labels, mask, vocab_size, interpret)
 
 
-def _fwd_value(h, w, labels, mask, vocab_size):
+def _fwd_value(h, w, labels, mask, vocab_size, interpret):
     B, S, d = h.shape
     N = B * S
     nll = fused_xent(h.reshape(N, d), w, labels.reshape(N),
-                     vocab_size=vocab_size, interpret=_use_interpret())
+                     vocab_size=vocab_size, interpret=interpret)
     m = mask.reshape(N).astype(jnp.float32)
     return jnp.sum(nll * m), jnp.sum(m)
 
 
-def _fwd(h, w, labels, mask, vocab_size):
-    out = _fwd_value(h, w, labels, mask, vocab_size)
+def _fwd(h, w, labels, mask, vocab_size, interpret):
+    out = _fwd_value(h, w, labels, mask, vocab_size, interpret)
     return out, (h, w, labels, mask)
 
 
-def _bwd(vocab_size, res, g):
+def _bwd(vocab_size, interpret, res, g):
     h, w, labels, mask = res
     g_tot, _ = g
     B, S, d = h.shape

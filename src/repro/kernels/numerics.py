@@ -15,8 +15,10 @@ read it so the numbers cannot fork:
 The shape grids deliberately include the training shapes the benches never
 used: the ``paper-transformer-tiny`` / ``paper-ssm-tiny`` step-body shapes
 and ragged (non-128-aligned) axes that exercise ``tiling.divisor_tile``.
-All kernels run in interpret mode here (CPU container); on TPU the same
-sweep times and checks the Mosaic lowering.
+The gate runs every kernel in interpret mode, so it checks the kernel
+programs on any backend; the Mosaic lowering is compiled by
+``tests/test_tpu_compile.py`` and checked on the chip by ``chip_smoke.py``
+(``check_case(..., interpret=False)`` at model widths).
 """
 from __future__ import annotations
 
@@ -70,8 +72,10 @@ def iter_cases():
             yield ("ssd_scan", dt, shp)
 
 
-def check_case(kernel: str, dtype_name: str, shape) -> dict:
-    """Run one (kernel, dtype, shape) cell -> report dict (no raising)."""
+def check_case(kernel: str, dtype_name: str, shape, *,
+               interpret: bool = True) -> dict:
+    """Run one (kernel, dtype, shape) cell -> report dict (no raising).
+    ``interpret=False`` runs the Mosaic kernel (TPU only)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -89,7 +93,7 @@ def check_case(kernel: str, dtype_name: str, shape) -> dict:
         h = jax.random.normal(key, (N, d), jnp.float32).astype(dtype)
         w = (jax.random.normal(sub(1), (d, Vp), jnp.float32) * 0.05).astype(dtype)
         y = jax.random.randint(sub(2), (N,), 0, V)
-        out = fused_xent(h, w, y, vocab_size=V)
+        out = fused_xent(h, w, y, vocab_size=V, interpret=interpret)
         ref = xent_ref(h, w, y, vocab_size=V)
         outs, refs = [out], [ref]
     elif kernel == "flash_attention":
@@ -98,7 +102,8 @@ def check_case(kernel: str, dtype_name: str, shape) -> dict:
         q = jax.random.normal(key, (BH, S, hd), jnp.float32).astype(dtype)
         k = jax.random.normal(sub(1), (BH, S, hd), jnp.float32).astype(dtype)
         v = jax.random.normal(sub(2), (BH, S, hd), jnp.float32).astype(dtype)
-        out = flash_attention(q, k, v, causal=causal, window=window)
+        out = flash_attention(q, k, v, causal=causal, window=window,
+                              interpret=interpret)
         ref = attention_ref(q, k, v, causal=causal, window=window)
         outs, refs = [out], [ref]
     else:
@@ -109,7 +114,8 @@ def check_case(kernel: str, dtype_name: str, shape) -> dict:
         A = -jnp.exp(jax.random.normal(sub(2), (nh,)) * 0.3)
         B = jax.random.normal(sub(3), (b, S, G, ds), jnp.float32).astype(dtype)
         C = jax.random.normal(sub(4), (b, S, G, ds), jnp.float32).astype(dtype)
-        y1, s1 = ssd_chunked_pallas(x, dt, A, B, C, chunk=chunk)
+        y1, s1 = ssd_chunked_pallas(x, dt, A, B, C, chunk=chunk,
+                                    interpret=interpret)
         y2, s2 = ssd_ref(x, dt, A, B, C, chunk=chunk)
         outs, refs = [y1, s1], [y2, s2]
 

@@ -15,11 +15,7 @@ import jax.numpy as jnp
 from repro.kernels.ssd_scan.kernel import ssd_intra_chunk
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _ssd_pallas_fwd(x, dt, A, B, C, chunk: int):
+def _ssd_pallas_fwd(x, dt, A, B, C, chunk: int, interpret: bool):
     """Same contract as models.ssm.ssd_chunked.
 
     x: (b, S, nh, hd); dt: (b, S, nh); A: (nh,); B/C: (b, S, G, ds).
@@ -33,18 +29,17 @@ def _ssd_pallas_fwd(x, dt, A, B, C, chunk: int):
     nc = S // cl
     rep = nh // G
 
-    Bh = jnp.repeat(B, rep, axis=-2)
-    Ch = jnp.repeat(C, rep, axis=-2)
-    xr = x.reshape(b * nc, cl, nh, hd)
-    dtr = dt.reshape(b * nc, cl, nh)
-    Br = Bh.reshape(b * nc, cl, nh, ds)
-    Cr = Ch.reshape(b * nc, cl, nh, ds)
+    # head-/group-major chunks for the kernel (see kernel.py)
+    def chunks(a):                # (b, S, h, ...) -> (b·nc, h, cl, ...)
+        return jnp.moveaxis(a.reshape((b * nc, cl) + a.shape[2:]), 1, 2)
 
-    y_diag, states, decays = ssd_intra_chunk(
-        xr, dtr, A, Br, Cr, interpret=_use_interpret())
-    y_diag = y_diag.reshape(b, nc, cl, nh, hd)
+    dt = dt.astype(jnp.float32)
+    cum = jnp.cumsum(chunks(dt * A), axis=-1)              # (b·nc, nh, cl)
+    y_diag, states = ssd_intra_chunk(chunks(x), chunks(dt), cum, chunks(B),
+                                     chunks(C), interpret=interpret)
+    y_diag = jnp.moveaxis(y_diag, 1, 2).reshape(b, nc, cl, nh, hd)
     states = states.reshape(b, nc, nh, hd, ds)
-    decays = decays.reshape(b, nc, nh)
+    decays = jnp.exp(cum[..., -1]).reshape(b, nc, nh)
 
     def step(state, inp):
         s_n, d_n = inp
@@ -57,25 +52,23 @@ def _ssd_pallas_fwd(x, dt, A, B, C, chunk: int):
     prevs = jnp.moveaxis(prevs, 0, 1)                      # (b, nc, nh, hd, ds)
 
     # off-diagonal: Y_off[i] = C_i · prev_state · exp(cum_i)
-    dA = (dtr * A).reshape(b, nc, cl, nh)
-    cum = jnp.cumsum(jnp.moveaxis(dA, -1, -2), axis=-1)     # (b, nc, nh, cl)
-    Y_off = jnp.einsum("bnihd,bnhpd,bnhi->bnihp",
-                       Cr.reshape(b, nc, cl, nh, ds).astype(jnp.float32),
-                       prevs, jnp.exp(cum))
+    Ch = jnp.repeat(C, rep, axis=-2).reshape(b, nc, cl, nh, ds)
+    Y_off = jnp.einsum("bnihd,bnhpd,bnhi->bnihp", Ch.astype(jnp.float32),
+                       prevs, jnp.exp(cum).reshape(b, nc, nh, cl))
     y = (y_diag + Y_off).reshape(b, S, nh, hd)
     return y, final_state
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _ssd(x, dt, A, B, C, chunk):
-    return _ssd_pallas_fwd(x, dt, A, B, C, chunk)
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd(x, dt, A, B, C, chunk, interpret):
+    return _ssd_pallas_fwd(x, dt, A, B, C, chunk, interpret)
 
 
-def _ssd_fwd(x, dt, A, B, C, chunk):
-    return _ssd(x, dt, A, B, C, chunk), (x, dt, A, B, C)
+def _ssd_fwd(x, dt, A, B, C, chunk, interpret):
+    return _ssd(x, dt, A, B, C, chunk, interpret), (x, dt, A, B, C)
 
 
-def _ssd_bwd(chunk, res, g):
+def _ssd_bwd(chunk, interpret, res, g):
     x, dt, A, B, C = res
     from repro.models.ssm import ssd_chunked   # lazy: models lazily import us
     _, vjp = jax.vjp(
@@ -88,6 +81,8 @@ def _ssd_bwd(chunk, res, g):
 _ssd.defvjp(_ssd_fwd, _ssd_bwd)
 
 
-def ssd_chunked_pallas(x, dt, A, B, C, *, chunk: int):
-    """Trainable surface — see module docstring; contract of ``_ssd_pallas_fwd``."""
-    return _ssd(x, dt, A, B, C, chunk)
+def ssd_chunked_pallas(x, dt, A, B, C, *, chunk: int, interpret: bool):
+    """Trainable surface — see module docstring; contract of
+    ``_ssd_pallas_fwd``.  ``interpret`` runs the kernel through the Pallas
+    interpreter instead of Mosaic."""
+    return _ssd(x, dt, A, B, C, chunk, interpret)

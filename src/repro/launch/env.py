@@ -184,6 +184,31 @@ def p0print(*args, **kwargs) -> None:
     CONSOLE.print(*args, **kwargs)
 
 
+#: The persistent compilation cache's directory when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: fixed and inside the checkout
+#: (git-ignored), so a later run of the same checkout finds its entries.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def setup_compilation_cache(env: Optional[Mapping] = None) -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Call at a program's start, never at import.  A set
+    ``JAX_COMPILATION_CACHE_DIR`` wins: JAX reads the variable itself and
+    no other directory is set in code.  Otherwise the cache goes to
+    :data:`DEFAULT_COMPILATION_CACHE_DIR` — never a temp dir or a path
+    keyed on the pid or the time, which no later run would find."""
+    env = os.environ if env is None else env
+    if env.get("JAX_COMPILATION_CACHE_DIR"):
+        return env["JAX_COMPILATION_CACHE_DIR"]
+    import jax
+    jax.config.update("jax_compilation_cache_dir",
+                      DEFAULT_COMPILATION_CACHE_DIR)
+    return DEFAULT_COMPILATION_CACHE_DIR
+
+
 def add_process_args(parser) -> None:
     """The shared ``--coordinator/--num-processes/--process-id`` CLI
     surface (launch/train, parity harnesses, benchmarks)."""

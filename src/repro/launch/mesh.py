@@ -42,14 +42,10 @@ class MeshError(ValueError):
 
 
 def _make_mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: ``axis_types`` only exists on
-    newer jax (jax.sharding.AxisType landed after 0.4.x); default behaviour
-    there is Auto, which is what we want everywhere."""
-    kwargs = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices, **kwargs)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-partitioned): the
+    engines place and constrain shardings themselves."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def global_device_order(devices=None) -> list:

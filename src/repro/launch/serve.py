@@ -25,8 +25,8 @@ newer one between decode steps (in-flight requests keep their KV; each
 completion records the snapshot generations that served it).
 
 ``--kernels`` honors the same kernel-selection contract as training
-(``repro.kernels.policy``): ``pallas`` resolves to the reference paths
-off-TPU.  Timed throughput excludes compile: a warmup pass runs first and
+(``repro.kernels.policy``): ``pallas`` needs a TPU backend and exits with
+an error elsewhere.  Timed throughput excludes compile: a warmup pass runs first and
 its wall (≈ jit compile) is reported separately.
 """
 from __future__ import annotations
@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ZOO_MODELS, ZOO_TIERS, get_config, zoo_config
+from repro.launch import env as ENV
 from repro.models import build_model
 from repro.obs.stats import percentile
 from repro.obs.timing import maybe_profile
@@ -151,8 +152,8 @@ def main():
     ap.add_argument("--kernels", default="reference",
                     choices=["pallas", "reference", "interpret"],
                     help="hot-spot implementations — the same contract as "
-                         "training (repro.kernels.policy; pallas falls "
-                         "back to reference off-TPU)")
+                         "training (repro.kernels.policy; pallas needs a "
+                         "TPU backend)")
     ap.add_argument("--precision", default="bf16", choices=["bf16", "f32"],
                     help="param/compute dtype; must match the trainer's "
                          "when restoring published snapshots")
@@ -200,9 +201,13 @@ def main():
                          "into this directory")
     args = ap.parse_args()
 
+    ENV.setup_compilation_cache()
     cfg = build_cfg(args)
-    from repro.kernels.policy import kernels_note, resolve_kernels
-    print(kernels_note(args.kernels, resolve_kernels(args.kernels)))
+    from repro.kernels.policy import resolve_kernels
+    try:
+        print(f"kernels: {resolve_kernels(args.kernels)}")
+    except RuntimeError as e:
+        raise SystemExit(str(e))
     model = build_model(
         cfg, kernels=args.kernels,
         param_dtype=jnp.float32 if args.precision == "f32" else jnp.bfloat16)

@@ -67,10 +67,15 @@ Model selection: ``--arch`` names an assigned architecture config
 transformer|moe|ssm`` picks the ``paper_transformer`` zoo family instead
 (``--tier tiny|base``).  ``--kernels pallas|reference|interpret`` routes the
 step-body hot spots (flash-attention, fused-xent, ssd_scan) —
-``pallas`` falls back to the ``ref.py`` paths where Pallas lowering is
-unavailable (see ``repro.kernels.policy``); ``--precision bf16|f32`` is the
+``pallas`` needs a TPU backend and exits with an error elsewhere (see
+``repro.kernels.policy``); ``--precision bf16|f32`` is the
 compute dtype (ψ statistics and the SPC queue stay f32 either way);
 ``--remat full|tp_out|none`` sets the chunk-scan-boundary checkpoint policy.
+``--devices N`` trains on the first N devices of a single process.
+
+``main(argv)`` can be called in-process and returns a :class:`TrainResult`
+(``chip_smoke.py`` drives it that way).  JAX's persistent compilation cache
+is on (``repro.launch.env.setup_compilation_cache``).
 
 Multi-process (ROADMAP: multi-host 3-D mesh scale-out): every runner
 accepts the shared ``--coordinator/--num-processes/--process-id`` surface
@@ -89,7 +94,7 @@ here, at the CLI boundary — library code never exits.
   PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
       --reduced --steps 30 --batch 8 --seq 128
   PYTHONPATH=src python -m repro.launch.train --model transformer \
-      --kernels pallas --chunk-steps 32 --steps 64 --batch 8 --seq 64
+      --kernels interpret --chunk-steps 32 --steps 64 --batch 8 --seq 64
   XLA_FLAGS=--xla_force_host_platform_device_count=8 PYTHONPATH=src \
       python -m repro.launch.train --arch internlm2-1.8b --reduced \
       --engine hybrid --model-parallel 2 --chunk-steps 8 --steps 32 \
@@ -104,6 +109,8 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import time
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -151,28 +158,52 @@ def ring_epoch(cfg, sampler, batch_size: int):
     return epoch
 
 
+@dataclass
+class TrainResult:
+    """What :func:`main` returns to an in-process caller."""
+
+    state: object             # final ISGDState (accel_count, sub_iters, queue)
+    seconds: float            # wall of the training loop, compiles included
+    steps: int                # steps this invocation ran
+    n_params: int = 0
+    kernels: str = "reference"
+    #: fused engine: one entry per dispatch — ``step`` (global step after
+    #: it), ``wall_s`` (dispatch to fetched metrics; the first includes
+    #: the compile) and ``metrics`` (host copies of the stacked metrics)
+    chunks: list = field(default_factory=list)
+    #: ``memory_stats()`` of each mesh device right after the loop, while
+    #: params, state and the ring are live (None where a backend has none)
+    memory: list = field(default_factory=list)
+
+
 def _drive_chunks(jchunk, state, params, ring, steps: int, k: int, *,
                   start: int = 0, ckpt=None, obs=None):
     """Run from global step ``start`` to ``steps`` (rounded up to whole
     chunks) through a fused chunk fn, printing the last step of each chunk.
     ``start`` may sit mid-chunk relative to the K grid — ``chunk_fn`` takes
     an arbitrary ``j0`` (what makes resume-from-checkpoint possible).
-    Returns (state, total_steps).  ``obs`` ingests each chunk's stacked
-    metrics at the chunk boundary (the fetch below is already the one host
-    sync per chunk — obs adds no dispatches)."""
+    Returns (state, total_steps, chunks) with ``chunks`` as in
+    :class:`TrainResult`.  ``obs`` ingests each chunk's stacked metrics at
+    the chunk boundary (the fetch below is already the one host sync per
+    chunk — obs adds no dispatches)."""
     j = start
+    chunks = []
     while j < steps:
+        t0 = time.perf_counter()
         state, params, ms = jchunk(state, params, ring.arrays, j)
+        ms = jax.device_get(ms)
+        wall = time.perf_counter() - t0
         if obs is not None:
             obs.chunk(j, ms)
         j += k
+        chunks.append({"step": j, "wall_s": wall, "metrics": ms})
         ENV.p0print(f"step {j:4d} loss={float(ms['loss'][-1]):.4f} "
               f"psi_bar={float(ms['psi_bar'][-1]):.4f} "
               f"limit={float(ms['limit'][-1]):.4f} "
               f"accel={bool(ms['accelerated'][-1])}")
         if ckpt is not None:
             ckpt.maybe_save(j, params=params, state=state)
-    return state, j
+    return state, j, chunks
 
 
 def _drive_scheduled(jfn, state, params, sched_state, ring, steps: int,
@@ -332,20 +363,28 @@ def run_sync(args, cfg, model, sampler, rule, icfg, lr_fn, *,
              engine: str = "hybrid", obs=None):
     """The synchronous engines — ``hybrid`` (DP × TP, 2-D mesh) and
     ``data-parallel`` (1-D mesh) — one driving loop, one step path
-    (``make_step_core`` under the hybrid shard_map engine).  Returns
-    ``(state, wall_seconds, steps_run)``.  ``obs`` (a
-    ``repro.obs.TrainObserver``) ingests metrics at the existing chunk/log
-    boundaries only."""
+    (``make_step_core`` under the hybrid shard_map engine).  Returns a
+    :class:`TrainResult`.  ``obs`` (a ``repro.obs.TrainObserver``) ingests
+    metrics at the existing chunk/log boundaries only."""
     timer = obs.timer if obs is not None else StepTimer()
+    devices = None
+    if args.devices:
+        if ENV.topology().num_processes > 1:
+            raise SystemExit("--devices picks devices of a single process; "
+                             "multi-process runs use every global device")
+        if args.devices > len(jax.devices()):
+            raise SystemExit(f"--devices {args.devices}: this process has "
+                             f"{len(jax.devices())}")
+        devices = jax.devices()[:args.devices]
     if engine == "data-parallel":
         if args.model_parallel != 1:
             raise SystemExit("--model-parallel composes with --engine "
                              "hybrid, not --engine data-parallel")
-        mesh = make_data_mesh()
+        mesh = make_data_mesh(devices)
     else:
         # pod defaults to the process count: 2-D (data, model) single-
         # process, 3-D (pod, data, model) over global devices otherwise
-        mesh = make_training_mesh(model=args.model_parallel)
+        mesh = make_training_mesh(model=args.model_parallel, devices=devices)
     multiproc = is_multiprocess(mesh)
     from repro.distributed.data_parallel import data_axis_size
     n_data = data_axis_size(mesh)
@@ -410,6 +449,12 @@ def run_sync(args, cfg, model, sampler, rule, icfg, lr_fn, *,
                               recorder=obs.recorder if obs is not None else None)
     start = 0
 
+    def result(state, steps, chunks=()):
+        # called inside the mesh context, while params/state/ring are live
+        return TrainResult(state, timer.seconds("train"), steps - start,
+                           n_params=n_params, chunks=list(chunks),
+                           memory=[d.memory_stats() for d in mesh.devices.flat])
+
     put_repl = ((lambda t, _sh: replicate_to_mesh(t, mesh)) if multiproc
                 else jax.device_put)
     with mesh, ctx:
@@ -431,7 +476,7 @@ def run_sync(args, cfg, model, sampler, rule, icfg, lr_fn, *,
                                                 sched_state, ring, args.steps,
                                                 args.chunk_steps, start=start,
                                                 ckpt=ckpt, obs=obs)
-            return state, timer.seconds("train"), steps - start
+            return result(state, steps)
         ck = _maybe_resume(args, ckpt, params_like=params, state_like=state)
         if ck is not None:
             params = put_repl(ck.params, p_sh)
@@ -445,10 +490,10 @@ def run_sync(args, cfg, model, sampler, rule, icfg, lr_fn, *,
                               args.batch, mesh=mesh, axis=None,
                               relayout=not tp)
             with timer.span("train"):
-                state, steps = _drive_chunks(jstep, state, params, ring,
-                                             args.steps, args.chunk_steps,
-                                             start=start, ckpt=ckpt, obs=obs)
-            return state, timer.seconds("train"), steps - start
+                state, steps, chunks = _drive_chunks(
+                    jstep, state, params, ring, args.steps, args.chunk_steps,
+                    start=start, ckpt=ckpt, obs=obs)
+            return result(state, steps, chunks)
 
         if multiproc:
             # the host prefetcher's device_put cannot address other
@@ -490,7 +535,7 @@ def run_sync(args, cfg, model, sampler, rule, icfg, lr_fn, *,
                     ckpt.maybe_save(j + 1, params=params, state=state)
             if obs is not None:
                 obs.flush()
-        return state, timer.seconds("train"), args.steps - start
+        return result(state, args.steps)
 
 
 def run_async_ps(args, cfg, model, sampler, rule, icfg, lr_fn, *, obs=None):
@@ -580,10 +625,10 @@ def run_async_ps(args, cfg, model, sampler, rule, icfg, lr_fn, *, obs=None):
     print(f"staleness: mean_tau={sum(taus)/len(taus):.2f} "
           f"max_tau={max(taus)} "
           f"bound={(2 * args.max_staleness + 1) * (args.workers - 1)}")
-    return state, dt, len(records)
+    return TrainResult(state, dt, len(records), n_params=n_params)
 
 
-def main():
+def main(argv=None) -> TrainResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="assigned architecture config (repro.configs)")
@@ -597,8 +642,9 @@ def main():
                     help="use the smoke-scale variant (CPU)")
     ap.add_argument("--kernels", default="reference",
                     choices=["pallas", "reference", "interpret"],
-                    help="step-body hot-spot implementations; pallas falls "
-                         "back to the ref.py paths off-TPU "
+                    help="step-body hot-spot implementations: pallas = "
+                         "Mosaic kernels (TPU only), interpret = the same "
+                         "kernels through the Pallas interpreter "
                          "(repro.kernels.policy)")
     ap.add_argument("--precision", default="bf16", choices=["bf16", "f32"],
                     help="compute dtype for params/activations (psi "
@@ -616,6 +662,9 @@ def main():
     ap.add_argument("--k-sigma", type=float, default=2.0)
     ap.add_argument("--stop", type=int, default=3)
     ap.add_argument("--n-seqs", type=int, default=64)
+    ap.add_argument("--devices", type=int, default=0,
+                    help="train on the first N devices of this process "
+                         "(0 = all; single-process runs only)")
     ap.add_argument("--model-parallel", type=int, default=1,
                     help="hybrid engine: devices on the tensor-parallel "
                          "'model' axis (must divide the device count; the "
@@ -706,7 +755,8 @@ def main():
                          "directory (named annotations around the chunk "
                          "scan, psi push, accelerate subproblem, PS fold)")
     ENV.add_process_args(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    ENV.setup_compilation_cache()
 
     # before any device use: latency-hiding flags + the process group
     ENV.apply_async_collective_flags()
@@ -730,10 +780,14 @@ def main():
         cfg = get_config(args.arch)
         if args.reduced:
             cfg = cfg.reduced()
-    from repro.kernels.policy import kernels_note, resolve_kernels
-    ENV.p0print(kernels_note(args.kernels, resolve_kernels(args.kernels)))
+    from repro.kernels.policy import resolve_kernels
+    try:
+        kernels = resolve_kernels(args.kernels)
+    except RuntimeError as e:
+        raise SystemExit(str(e))
+    ENV.p0print(f"kernels: {kernels}")
     model = build_model(
-        cfg, kernels=args.kernels,
+        cfg, kernels=kernels,
         param_dtype=jnp.float32 if args.precision == "f32" else jnp.bfloat16,
         remat=args.remat != "none",
         remat_policy="tp_out" if args.remat == "tp_out" else "full")
@@ -754,30 +808,31 @@ def main():
     try:
         with maybe_profile(args.profile_dir):
             if engine == "async-ps":
-                state, dt, steps = run_async_ps(args, cfg, model, sampler,
-                                                rule, icfg, lr_fn, obs=obs)
+                res = run_async_ps(args, cfg, model, sampler, rule, icfg,
+                                   lr_fn, obs=obs)
             else:
-                state, dt, steps = run_sync(args, cfg, model, sampler, rule,
-                                            icfg, lr_fn, engine=engine,
-                                            obs=obs)
+                res = run_sync(args, cfg, model, sampler, rule, icfg, lr_fn,
+                               engine=engine, obs=obs)
     except MeshError as e:
         # the CLI boundary: library validation errors become exit codes
         raise SystemExit(str(e))
     if obs is not None:
         # a resumed run missed the pre-restart pushes: chart only, no
         # reconcile claim
-        final = obs.finalize(None if args.resume else state,
-                             steps=steps, wall=dt)
+        final = obs.finalize(None if args.resume else res.state,
+                             steps=res.steps, wall=res.seconds)
         if ENV.is_coordinator():
             from repro.obs.recorder import write_merged_summary
             write_merged_summary(args.obs_dir)
         ENV.p0print(f"obs: {args.obs_dir} "
                     f"spc_reconciled={final.get('reconciled', 'n/a')} "
                     f"accel_events={final['accel_events']}")
-    ENV.p0print(f"done: {steps} steps in {dt:.1f}s "
-                f"({dt/steps*1e3:.0f} ms/step) "
-                f"accelerated={int(state.accel_count)} "
-                f"sub_iters={int(state.sub_iters)}")
+    ENV.p0print(f"done: {res.steps} steps in {res.seconds:.1f}s "
+                f"({res.seconds/res.steps*1e3:.0f} ms/step) "
+                f"accelerated={int(res.state.accel_count)} "
+                f"sub_iters={int(res.state.sub_iters)}")
+    res.kernels = kernels
+    return res
 
 
 if __name__ == "__main__":

@@ -40,15 +40,15 @@ def _frontend_spec(cfg, B):
 
 
 def build_model(cfg: ModelConfig, *, remat: bool = True,
-                use_fused_xent: bool = False,
                 remat_policy: str = "full",
                 kernels: str = "reference",
                 param_dtype=jnp.bfloat16) -> Model:
     """``kernels`` ∈ {'pallas', 'reference', 'interpret'} picks the step-body
-    hot-spot implementations (``repro.kernels.policy``): 'pallas' resolves
-    to the reference paths off-TPU (interpret mode is a correctness harness,
-    not a training path).  The choice is baked at build time — one HLO per
-    model, no in-step branching.
+    hot-spot implementations (``repro.kernels.policy``): 'pallas' needs a
+    TPU backend and raises elsewhere; 'interpret' runs the same kernels
+    through the Pallas interpreter (a correctness harness, not a training
+    path).  The choice is baked at build time — one HLO per model, no
+    in-step branching.
 
     ``param_dtype`` is the mixed-precision policy's compute dtype (params +
     activations; bf16 default).  Norm scales, ψ statistics, the loss scalars
@@ -56,16 +56,14 @@ def build_model(cfg: ModelConfig, *, remat: bool = True,
     ``trainer.make_loss_and_grad``.
     """
     from repro.kernels.policy import resolve_kernels
-    use_pallas = resolve_kernels(kernels) != "reference"
+    kernels = resolve_kernels(kernels)
 
     def init(key, max_seq: int = 4096):
         return T.init_params(key, cfg, max_seq=max_seq, dtype=param_dtype)
 
     def loss_fn(params, batch):
         return T.lm_loss_fn(params, cfg, batch, remat=remat,
-                            use_fused_xent=use_fused_xent,
-                            remat_policy=remat_policy,
-                            use_pallas=use_pallas)
+                            remat_policy=remat_policy, kernels=kernels)
 
     def prefill_fn(params, batch):
         return T.prefill(params, cfg, batch["tokens"],

@@ -154,13 +154,13 @@ def init_attn(key, cfg, dtype=jnp.bfloat16):
 
 
 def attn_forward(params, cfg, x, positions, *, window, use_rope=True,
-                 q_chunk=DEFAULT_Q_CHUNK, use_flash=False):
+                 q_chunk=DEFAULT_Q_CHUNK, kernels="reference"):
     """Full-sequence causal attention. x: (B, S, d).
 
-    ``use_flash`` swaps the chunked-scan reference path for the Pallas
-    flash kernel (same GQA layout; numerically equal within the
-    ``repro.kernels.numerics`` tolerances, bit-identical in neither
-    direction — the switch is per-``build_model``, never per-step).
+    ``kernels`` ``pallas``/``interpret`` swaps the chunked-scan reference
+    path for the Pallas flash kernel (same GQA layout; numerically equal
+    within the ``repro.kernels.numerics`` tolerances, bit-identical in
+    neither direction — the switch is per-``build_model``, never per-step).
     """
     B, S, _ = x.shape
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
@@ -170,9 +170,10 @@ def attn_forward(params, cfg, x, positions, *, window, use_rope=True,
     if use_rope:
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
-    if use_flash:
+    if kernels != "reference":
         from repro.kernels.flash_attention.ops import gqa_flash
-        o = gqa_flash(q, k, v, causal=True, window=window)
+        o = gqa_flash(q, k, v, causal=True, window=window,
+                      interpret=kernels == "interpret")
     else:
         o = _attend_chunked(q, k, v, causal=True, window=window,
                             q_chunk=q_chunk)

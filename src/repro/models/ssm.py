@@ -122,7 +122,7 @@ def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
     return y, final_state
 
 
-def ssm_forward(params, cfg, x, use_pallas: bool = False):
+def ssm_forward(params, cfg, x, kernels: str = "reference"):
     """Full-sequence Mamba2 mixer. x: (B, S, d) -> (y, (conv_state, ssd_state))."""
     b, S, d = x.shape
     di, nh, hd = cfg.d_inner, cfg.ssm_nheads, cfg.ssm_headdim
@@ -136,9 +136,10 @@ def ssm_forward(params, cfg, x, use_pallas: bool = False):
     Cm = xBC[..., di + G * ds:].reshape(b, S, G, ds)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
-    if use_pallas:
+    if kernels != "reference":
         from repro.kernels.ssd_scan.ops import ssd_chunked_pallas
-        y, ssd_state = ssd_chunked_pallas(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
+        y, ssd_state = ssd_chunked_pallas(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk,
+                                          interpret=kernels == "interpret")
     else:
         y, ssd_state = ssd_chunked(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
     y = y + params["D"][:, None] * xs.astype(jnp.float32)

@@ -152,23 +152,24 @@ def _apply_mlp(p, spec: LayerSpec, cfg, x, decode: bool):
 
 
 def apply_layer(p, cfg, spec: LayerSpec, x, positions, enc_out=None,
-                use_pallas=False):
+                kernels="reference"):
     """Full-sequence pass. Returns (x, cache_entry, aux).
 
-    ``use_pallas`` routes the mixer hot spots through the Pallas kernels
-    (flash attention / ssd_scan); MLA keeps the reference path — its latent
-    expansion has no kernel counterpart yet.
+    ``kernels`` (a resolved ``repro.kernels.policy`` mode) routes the mixer
+    hot spots through the Pallas kernels (flash attention / ssd_scan) when
+    it is ``pallas`` or ``interpret``; MLA keeps the reference path — its
+    latent expansion has no kernel counterpart yet.
     """
     h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     use_rope = cfg.family != "encdec"
     if spec.mixer == "attn":
         o, cache = L.attn_forward(p["mixer"], cfg, h, positions,
                                   window=spec.window, use_rope=use_rope,
-                                  use_flash=use_pallas)
+                                  kernels=kernels)
     elif spec.mixer == "mla":
         o, cache = L.mla_forward(p["mixer"], cfg, h, positions)
     else:
-        o, cache = S.ssm_forward(p["mixer"], cfg, h, use_pallas=use_pallas)
+        o, cache = S.ssm_forward(p["mixer"], cfg, h, kernels=kernels)
     # tag the row-parallel projection outputs: under remat_policy="tp_out"
     # these (post-all-reduce) activations are SAVED, so the backward pass
     # does not re-run the forward TP all-reduces (§Perf)
@@ -262,7 +263,7 @@ def _embed(params, cfg, tokens, frontend_embeds=None):
 
 
 def forward(params, cfg, tokens, frontend_embeds=None, *, want_cache=False,
-            remat=True, remat_policy="full", use_pallas=False):
+            remat=True, remat_policy="full", kernels="reference"):
     """-> (hidden (B,S,d), caches or None, aux)."""
     prefix_specs, block_specs, n_blocks = stack_plan(cfg)
     B, Sq = tokens.shape
@@ -278,7 +279,7 @@ def forward(params, cfg, tokens, frontend_embeds=None, *, want_cache=False,
     prefix_caches, aux_total = [], 0.0
     for sp, lp in zip(prefix_specs, params["prefix"]):
         x, cache, aux = apply_layer(lp, cfg, sp, x, positions, enc_out,
-                                    use_pallas=use_pallas)
+                                    kernels=kernels)
         aux_total += aux
         prefix_caches.append(cache)
 
@@ -287,7 +288,7 @@ def forward(params, cfg, tokens, frontend_embeds=None, *, want_cache=False,
         caches = []
         for p, sp in enumerate(block_specs):
             x, cache, a = apply_layer(block_params[p], cfg, sp, x, positions,
-                                      enc_out, use_pallas=use_pallas)
+                                      enc_out, kernels=kernels)
             aux += a
             caches.append(cache)
         ys = tuple(caches) if want_cache else None
@@ -350,7 +351,7 @@ def chunked_xent(params, cfg, h, labels, mask, chunk: int = LOSS_CHUNK):
 
 
 def lm_loss_fn(params, cfg, batch, *, aux_weight=0.01, remat=True,
-               use_fused_xent=False, remat_policy="full", use_pallas=False):
+               remat_policy="full", kernels="reference"):
     """Next-token CE averaged over valid positions. batch: {'tokens', ...}.
 
     Returns f32 ``(total_loss, data_loss)`` scalars regardless of the
@@ -360,16 +361,17 @@ def lm_loss_fn(params, cfg, batch, *, aux_weight=0.01, remat=True,
     tokens = batch["tokens"]
     fe = batch.get("frontend_embeds")
     h, _, aux = forward(params, cfg, tokens, fe, want_cache=False, remat=remat,
-                        remat_policy=remat_policy, use_pallas=use_pallas)
+                        remat_policy=remat_policy, kernels=kernels)
     labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
     mask = jnp.ones_like(tokens, jnp.float32).at[:, -1].set(0.0)
     if cfg.family == "vlm":
         n = cfg.num_image_tokens
         mask = mask.at[:, :n].set(0.0)
-    if use_fused_xent or use_pallas:
+    if kernels != "reference":
         from repro.kernels.fused_xent.ops import fused_xent_sum
         w = params["embed"].T if cfg.tie_embeddings else params["head"]
-        tot, cnt = fused_xent_sum(h, w, labels, mask, cfg.vocab_size)
+        tot, cnt = fused_xent_sum(h, w, labels, mask, cfg.vocab_size,
+                                  kernels == "interpret")
     else:
         tot, cnt = chunked_xent(params, cfg, h, labels, mask)
     loss = (tot / jnp.maximum(cnt, 1.0)).astype(jnp.float32)

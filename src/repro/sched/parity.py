@@ -48,7 +48,7 @@ def run_sched_parity(steps: int = 32, verbose: bool = False) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core import ISGDConfig
@@ -181,7 +181,7 @@ def run_sched_parity(steps: int = 32, verbose: bool = False) -> dict:
         return t[None]
 
     per_shard = shard_map(draws, mesh=mesh, in_specs=(P(), P(), P()),
-                          out_specs=P("data"), check_rep=False)
+                          out_specs=P("data"), check_vma=False)
     table = jnp.asarray(rng.rand(n_batches).astype(np.float32)) * 3.0
     visits = jnp.ones((n_batches,), jnp.int32)
     agree = True

@@ -279,3 +279,23 @@ def test_trainlog_extend_matches_append():
     # chunk-end walls are estimates; per-step appends default to real walls
     assert got.wall_est == [True] * 8
     assert ref.wall_est == [False] * 8
+
+
+def test_launcher_main_in_process_returns_chunk_record(monkeypatch, tmp_path):
+    """``launch.train.main(argv)`` runs in-process and hands back what an
+    in-process caller (``chip_smoke.py``) reads: the resolved kernel mode,
+    one record per fused dispatch, and per-device memory stats."""
+    from repro.launch.train import main
+    # a set variable keeps main from turning on the in-checkout cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    res = main(["--model", "transformer", "--chunk-steps", "4", "--steps",
+                "8", "--batch", "8", "--seq", "32", "--n-seqs", "32",
+                "--devices", "1"])
+    assert res.kernels == "reference" and res.steps == 8
+    assert [c["step"] for c in res.chunks] == [4, 8]
+    for c in res.chunks:
+        assert c["wall_s"] > 0.0
+        assert np.isfinite(c["metrics"]["loss"]).all()
+        assert np.asarray(c["metrics"]["loss"]).shape == (4,)
+    assert len(res.memory) == 1
+    assert int(res.state.accel_count) >= 0

@@ -38,7 +38,7 @@ def test_local_reduce_is_identity():
 
 
 def test_axis_reduce_means_over_mesh_axis():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_data_mesh()
@@ -47,11 +47,11 @@ def test_axis_reduce_means_over_mesh_axis():
     x = jnp.arange(4 * n, dtype=jnp.float32)
 
     f = shard_map(lambda s: rctx.scalar(jnp.mean(s)), mesh=mesh,
-                  in_specs=P("data"), out_specs=P(), check_rep=False)
+                  in_specs=P("data"), out_specs=P(), check_vma=False)
     np.testing.assert_allclose(float(f(x)), float(jnp.mean(x)), rtol=1e-6)
 
     g = shard_map(lambda s: rctx.sum_scalar(jnp.sum(s)), mesh=mesh,
-                  in_specs=P("data"), out_specs=P(), check_rep=False)
+                  in_specs=P("data"), out_specs=P(), check_vma=False)
     np.testing.assert_allclose(float(g(x)), float(jnp.sum(x)), rtol=1e-6)
 
     # hashable + frozen: jit specializes without retracing per call
@@ -63,7 +63,7 @@ def test_reduce_ctx_hashable_and_jit_specializes_without_retrace():
     contexts hit the jit cache (no retrace), distinct ones retrace once."""
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.reduce import StalenessReduce
@@ -110,7 +110,7 @@ def test_reduce_ctx_hashable_and_jit_specializes_without_retrace():
             ax_traces.append(ctx.axis)
             return ctx.scalar(jnp.mean(s))
         return shard_map(inner, mesh=mesh, in_specs=P("data"), out_specs=P(),
-                         check_rep=False)(x)
+                         check_vma=False)(x)
 
     n = mesh.shape["data"]
     xx = jnp.arange(4 * n, dtype=jnp.float32)

@@ -125,3 +125,31 @@ def test_single_process_call_respects_recorded_topology(monkeypatch):
                                    coordinator="127.0.0.1:1234")
     monkeypatch.setattr(ENV, "_TOPOLOGY", recorded)
     assert ENV.initialize_distributed() is recorded
+
+
+# ---------------------------------------------------------------------------
+# setup_compilation_cache: env var wins, else a fixed in-checkout path
+# ---------------------------------------------------------------------------
+def test_compilation_cache_env_var_wins():
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    env = {"JAX_COMPILATION_CACHE_DIR": "/elsewhere/cache"}
+    assert ENV.setup_compilation_cache(env=env) == "/elsewhere/cache"
+    # JAX reads the variable itself: no directory is set in code
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compilation_cache_default_is_fixed_and_git_ignored():
+    import os
+
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = ENV.setup_compilation_cache(env={})
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert path == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -26,7 +26,7 @@ def test_fused_xent_sweep(N, d, Vp, V, dtype):
     w = (jax.random.normal(jax.random.fold_in(KEY, 1), (d, Vp), jnp.float32)
          * 0.05).astype(dtype)
     labels = jax.random.randint(jax.random.fold_in(KEY, 2), (N,), 0, V)
-    out = fused_xent(h, w, labels, vocab_size=V, bn=64, bv=256)
+    out = fused_xent(h, w, labels, vocab_size=V, bn=64, bv=256, interpret=True)
     ref = xent_ref(h, w, labels, vocab_size=V)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
@@ -37,7 +37,7 @@ def test_fused_xent_gold_never_in_padding():
     h = jax.random.normal(KEY, (N, d))
     w = jax.random.normal(jax.random.fold_in(KEY, 1), (d, Vp)) * 0.05
     labels = jnp.full((N,), V - 1)
-    out = fused_xent(h, w, labels, vocab_size=V, bn=64, bv=128)
+    out = fused_xent(h, w, labels, vocab_size=V, bn=64, bv=128, interpret=True)
     assert bool(jnp.isfinite(out).all())
 
 
@@ -57,7 +57,8 @@ def test_flash_attention_sweep(BH, S, hd, causal, window, dtype):
                           jnp.float32).astype(dtype)
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (BH, S, hd),
                           jnp.float32).astype(dtype)
-    out = flash_attention(q, k, v, causal=causal, window=window, bq=64, bk=64)
+    out = flash_attention(q, k, v, causal=causal, window=window, bq=64, bk=64,
+                          interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window)
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -69,7 +70,7 @@ def test_gqa_wrapper_matches_ref():
     q = jax.random.normal(KEY, (B, S, H, hd))
     k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, S, K, hd))
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, S, K, hd))
-    out = gqa_flash(q, k, v, bq=64, bk=64)
+    out = gqa_flash(q, k, v, bq=64, bk=64, interpret=True)
     ref = gqa_ref(q, k, v)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
@@ -82,7 +83,8 @@ def test_flash_matches_model_attention_path():
     k = jax.random.normal(jax.random.fold_in(KEY, 3), (B, S, H, hd))
     v = jax.random.normal(jax.random.fold_in(KEY, 4), (B, S, H, hd))
     model_out = _attend_chunked(q, k, v, causal=True, window=32, q_chunk=64)
-    kern_out = gqa_flash(q, k, v, causal=True, window=32, bq=64, bk=64)
+    kern_out = gqa_flash(q, k, v, causal=True, window=32, bq=64, bk=64,
+                         interpret=True)
     np.testing.assert_allclose(model_out, kern_out, rtol=2e-5, atol=2e-5)
 
 
@@ -100,7 +102,7 @@ def test_ssd_kernel_sweep(b, S, nh, hd, G, ds, chunk):
     A = -jnp.exp(jax.random.normal(jax.random.fold_in(KEY, 2), (nh,)) * 0.3)
     B = jax.random.normal(jax.random.fold_in(KEY, 3), (b, S, G, ds))
     C = jax.random.normal(jax.random.fold_in(KEY, 4), (b, S, G, ds))
-    y1, s1 = ssd_chunked_pallas(x, dt, A, B, C, chunk=chunk)
+    y1, s1 = ssd_chunked_pallas(x, dt, A, B, C, chunk=chunk, interpret=True)
     y2, s2 = ssd_ref(x, dt, A, B, C, chunk=chunk)
     np.testing.assert_allclose(y1, y2, rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(s1, s2, rtol=1e-3, atol=1e-3)
@@ -153,7 +155,7 @@ def test_fused_xent_custom_vjp_matches_ref():
     m = jnp.ones((B, S)).at[:, -1].set(0.0)
 
     def lf(h, w):
-        t, c = fused_xent_sum(h, w, y, m, V)
+        t, c = fused_xent_sum(h, w, y, m, V, True)
         return t / c
 
     def lr(h, w):
@@ -171,8 +173,8 @@ def test_model_trains_with_fused_xent():
     from repro.configs import get_config
     from repro.models import build_model
     cfg = get_config("internlm2_1_8b").reduced()
-    m1 = build_model(cfg, use_fused_xent=True)
-    m2 = build_model(cfg, use_fused_xent=False)
+    m1 = build_model(cfg, kernels="interpret")
+    m2 = build_model(cfg, kernels="reference")
     params = m1.init(KEY, max_seq=32)
     batch = {"tokens": jnp.ones((2, 32), jnp.int32)}
     (l1, _), g1 = jax.value_and_grad(m1.loss_fn, has_aux=True)(params, batch)
@@ -184,3 +186,28 @@ def test_model_trains_with_fused_xent():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=5e-2, atol=2e-2)
+
+
+def test_pallas_mode_needs_tpu_and_never_falls_back(monkeypatch):
+    """``pallas`` resolves to itself on a TPU backend and raises, naming
+    the backend, anywhere else — no silent reference fallback."""
+    from repro.kernels import policy
+    monkeypatch.setattr(policy.jax, "default_backend", lambda: "tpu")
+    assert policy.resolve_kernels("pallas") == "pallas"
+    monkeypatch.setattr(policy.jax, "default_backend", lambda: "cpu")
+    with pytest.raises(RuntimeError, match="TPU backend.*'cpu'"):
+        policy.resolve_kernels("pallas")
+    for mode in ("reference", "interpret"):
+        assert policy.resolve_kernels(mode) == mode
+    with pytest.raises(ValueError):
+        policy.resolve_kernels("mosaic")
+
+
+def test_launcher_rejects_pallas_off_tpu(monkeypatch, tmp_path):
+    from repro.kernels import policy
+    from repro.launch.train import main
+    # a set variable keeps main from turning on the in-checkout cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(policy.jax, "default_backend", lambda: "cpu")
+    with pytest.raises(SystemExit, match="needs a TPU backend"):
+        main(["--model", "transformer", "--kernels", "pallas"])
