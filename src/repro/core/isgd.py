@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.analysis.mode import in_analysis_mode
 from repro.core import control
 from repro.core.reduce import LOCAL, ReduceCtx
+from repro.obs.timing import ACCELERATE, PSI_PUSH, UPDATE, named_scope
 from repro.optim.base import UpdateRule
 
 
@@ -127,10 +128,11 @@ def isgd_step(rule: UpdateRule, cfg: ISGDConfig, loss_and_grad: Callable,
     (loss, aux), grads = loss_and_grad(params, batch)
 
     # line 21: vanilla base update
-    base_state, params = rule.apply(state.base, params, grads, lr)
+    with named_scope(UPDATE):
+        base_state, params = rule.apply(state.base, params, grads, lr)
 
     # lines 13-20: queue + control limit
-    with jax.named_scope("obs/psi_push"):
+    with named_scope(PSI_PUSH):
         queue = (control.push(state.queue, loss) if slot is None
                  else control.push_at(state.queue, slot, loss))
         limit = control.control_limit(queue, cfg.k_sigma)
@@ -141,7 +143,7 @@ def isgd_step(rule: UpdateRule, cfg: ISGDConfig, loss_and_grad: Callable,
         def lg(w):
             (l, _), g = loss_and_grad(w, batch)
             return l, g
-        with jax.named_scope("obs/accelerate"):
+        with named_scope(ACCELERATE):
             return solve_subproblem(lg, p, limit, loss, lr, cfg)
 
     def no_accel(p):
@@ -175,7 +177,8 @@ def consistent_step(rule: UpdateRule, loss_and_grad: Callable, state, params,
     ``slot`` as in :func:`isgd_step`."""
     loss_and_grad = reduce_ctx.wrap_loss_and_grad(loss_and_grad)
     (loss, aux), grads = loss_and_grad(params, batch)
-    base_state, params = rule.apply(state.base, params, grads, lr)
+    with named_scope(UPDATE):
+        base_state, params = rule.apply(state.base, params, grads, lr)
     queue = (control.push(state.queue, loss) if slot is None
              else control.push_at(state.queue, slot, loss))
     metrics = {

@@ -130,6 +130,7 @@ from repro.launch import shardings as SH
 from repro.launch.mesh import (MeshError, is_multiprocess, make_data_mesh,
                                make_training_mesh)
 from repro.models import build_model
+from repro.obs import timing as OT
 from repro.obs.timing import StepTimer, maybe_profile
 from repro.optim import RULES
 from repro.sharding import activation_sharding, rules
@@ -190,19 +191,24 @@ def _drive_chunks(jchunk, state, params, ring, steps: int, k: int, *,
     chunks = []
     while j < steps:
         t0 = time.perf_counter()
-        state, params, ms = jchunk(state, params, ring.arrays, j)
-        ms = jax.device_get(ms)
+        with OT.annotate(OT.TRAIN_DISPATCH):
+            state, params, ms = jchunk(state, params, ring.arrays, j)
+        with OT.annotate(OT.TRAIN_FETCH):
+            ms = jax.device_get(ms)
         wall = time.perf_counter() - t0
         if obs is not None:
-            obs.chunk(j, ms)
+            with OT.annotate(OT.TRAIN_OBS):
+                obs.chunk(j, ms)
         j += k
         chunks.append({"step": j, "wall_s": wall, "metrics": ms})
-        ENV.p0print(f"step {j:4d} loss={float(ms['loss'][-1]):.4f} "
-              f"psi_bar={float(ms['psi_bar'][-1]):.4f} "
-              f"limit={float(ms['limit'][-1]):.4f} "
-              f"accel={bool(ms['accelerated'][-1])}")
+        with OT.annotate(OT.TRAIN_LOG):
+            ENV.p0print(f"step {j:4d} loss={float(ms['loss'][-1]):.4f} "
+                        f"psi_bar={float(ms['psi_bar'][-1]):.4f} "
+                        f"limit={float(ms['limit'][-1]):.4f} "
+                        f"accel={bool(ms['accelerated'][-1])}")
         if ckpt is not None:
-            ckpt.maybe_save(j, params=params, state=state)
+            with OT.annotate(OT.TRAIN_CHECKPOINT):
+                ckpt.maybe_save(j, params=params, state=state)
     return state, j, chunks
 
 
@@ -234,20 +240,26 @@ def _drive_scheduled(jfn, state, params, sched_state, ring, steps: int,
         return state, steps
     j = start
     while j < steps:
-        state, params, sched_state, ms = jfn(state, params, sched_state,
-                                             ring.arrays, j)
+        with OT.annotate(OT.TRAIN_DISPATCH):
+            state, params, sched_state, ms = jfn(state, params, sched_state,
+                                                 ring.arrays, j)
+        with OT.annotate(OT.TRAIN_FETCH):
+            ms = jax.device_get(ms)
         if obs is not None:
-            obs.chunk(j, ms)
+            with OT.annotate(OT.TRAIN_OBS):
+                obs.chunk(j, ms)
         j += k
-        visits = selection_counts(ms["batch_idx"], ring.n_batches)
-        ENV.p0print(f"step {j:4d} loss={float(ms['loss'][-1]):.4f} "
-              f"psi_bar={float(ms['psi_bar'][-1]):.4f} "
-              f"limit={float(ms['limit'][-1]):.4f} "
-              f"accel={bool(ms['accelerated'][-1])} "
-              f"visits={visits.tolist()}")
+        with OT.annotate(OT.TRAIN_LOG):
+            visits = selection_counts(ms["batch_idx"], ring.n_batches)
+            ENV.p0print(f"step {j:4d} loss={float(ms['loss'][-1]):.4f} "
+                        f"psi_bar={float(ms['psi_bar'][-1]):.4f} "
+                        f"limit={float(ms['limit'][-1]):.4f} "
+                        f"accel={bool(ms['accelerated'][-1])} "
+                        f"visits={visits.tolist()}")
         if ckpt is not None:
-            ckpt.maybe_save(j, params=params, state=state,
-                            sched_state=sched_state)
+            with OT.annotate(OT.TRAIN_CHECKPOINT):
+                ckpt.maybe_save(j, params=params, state=state,
+                                sched_state=sched_state)
     return state, j
 
 

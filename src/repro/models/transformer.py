@@ -21,6 +21,7 @@ from repro.analysis.mode import scan_unroll
 from repro.models import layers as L
 from repro.models import moe as M
 from repro.models import ssm as S
+from repro.obs.timing import ATTN, LM_HEAD, MLP, SSM, named_scope
 from repro.sharding import constrain
 
 LOSS_CHUNK = 512
@@ -160,21 +161,22 @@ def apply_layer(p, cfg, spec: LayerSpec, x, positions, enc_out=None,
     it is ``pallas`` or ``interpret``; MLA keeps the reference path — its
     latent expansion has no kernel counterpart yet.
     """
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    use_rope = cfg.family != "encdec"
-    if spec.mixer == "attn":
-        o, cache = L.attn_forward(p["mixer"], cfg, h, positions,
-                                  window=spec.window, use_rope=use_rope,
-                                  kernels=kernels)
-    elif spec.mixer == "mla":
-        o, cache = L.mla_forward(p["mixer"], cfg, h, positions)
-    else:
-        o, cache = S.ssm_forward(p["mixer"], cfg, h, kernels=kernels)
-    # tag the row-parallel projection outputs: under remat_policy="tp_out"
-    # these (post-all-reduce) activations are SAVED, so the backward pass
-    # does not re-run the forward TP all-reduces (§Perf)
-    o = jax.ad_checkpoint.checkpoint_name(o, "tp_out")
-    x = x + o
+    with named_scope(SSM if spec.mixer == "ssm" else ATTN):
+        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        use_rope = cfg.family != "encdec"
+        if spec.mixer == "attn":
+            o, cache = L.attn_forward(p["mixer"], cfg, h, positions,
+                                      window=spec.window, use_rope=use_rope,
+                                      kernels=kernels)
+        elif spec.mixer == "mla":
+            o, cache = L.mla_forward(p["mixer"], cfg, h, positions)
+        else:
+            o, cache = S.ssm_forward(p["mixer"], cfg, h, kernels=kernels)
+        # tag the row-parallel projection outputs: under remat_policy="tp_out"
+        # these (post-all-reduce) activations are SAVED, so the backward pass
+        # does not re-run the forward TP all-reduces (§Perf)
+        o = jax.ad_checkpoint.checkpoint_name(o, "tp_out")
+        x = x + o
     if spec.cross:
         hx = L.rms_norm(x, p["ln_x"], cfg.norm_eps)
         ck = (enc_out @ p["cross"]["wk"]).reshape(
@@ -185,10 +187,11 @@ def apply_layer(p, cfg, spec: LayerSpec, x, positions, enc_out=None,
         x = x + o
         cache = cache + (ck, cv)
     if spec.mlp != "none":
-        h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-        y, aux = _apply_mlp(p["mlp"], spec, cfg, h, decode=False)
-        y = jax.ad_checkpoint.checkpoint_name(y, "tp_out")
-        x = x + y
+        with named_scope(MLP):
+            h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+            y, aux = _apply_mlp(p["mlp"], spec, cfg, h, decode=False)
+            y = jax.ad_checkpoint.checkpoint_name(y, "tp_out")
+            x = x + y
     else:
         aux = 0.0
     return constrain(x, "hidden"), cache, aux
@@ -304,7 +307,8 @@ def forward(params, cfg, tokens, frontend_embeds=None, *, want_cache=False,
         body = block_body
     (x, aux_total), block_caches = jax.lax.scan(
         body, (x, aux_total), params["blocks"], unroll=scan_unroll())
-    x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with named_scope(LM_HEAD):
+        x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     caches = (prefix_caches, block_caches) if want_cache else None
     return x, caches, aux_total
 
@@ -362,19 +366,20 @@ def lm_loss_fn(params, cfg, batch, *, aux_weight=0.01, remat=True,
     fe = batch.get("frontend_embeds")
     h, _, aux = forward(params, cfg, tokens, fe, want_cache=False, remat=remat,
                         remat_policy=remat_policy, kernels=kernels)
-    labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-    mask = jnp.ones_like(tokens, jnp.float32).at[:, -1].set(0.0)
-    if cfg.family == "vlm":
-        n = cfg.num_image_tokens
-        mask = mask.at[:, :n].set(0.0)
-    if kernels != "reference":
-        from repro.kernels.fused_xent.ops import fused_xent_sum
-        w = params["embed"].T if cfg.tie_embeddings else params["head"]
-        tot, cnt = fused_xent_sum(h, w, labels, mask, cfg.vocab_size,
-                                  kernels == "interpret")
-    else:
-        tot, cnt = chunked_xent(params, cfg, h, labels, mask)
-    loss = (tot / jnp.maximum(cnt, 1.0)).astype(jnp.float32)
+    with named_scope(LM_HEAD):
+        labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
+        mask = jnp.ones_like(tokens, jnp.float32).at[:, -1].set(0.0)
+        if cfg.family == "vlm":
+            n = cfg.num_image_tokens
+            mask = mask.at[:, :n].set(0.0)
+        if kernels != "reference":
+            from repro.kernels.fused_xent.ops import fused_xent_sum
+            w = params["embed"].T if cfg.tie_embeddings else params["head"]
+            tot, cnt = fused_xent_sum(h, w, labels, mask, cfg.vocab_size,
+                                      kernels == "interpret")
+        else:
+            tot, cnt = chunked_xent(params, cfg, h, labels, mask)
+        loss = (tot / jnp.maximum(cnt, 1.0)).astype(jnp.float32)
     return loss + aux_weight * jnp.asarray(aux, jnp.float32), loss
 
 

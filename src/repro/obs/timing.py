@@ -17,10 +17,16 @@ the jax-free sweep parents):
 * :func:`maybe_profile` — context manager around a run; starts a
   ``jax.profiler`` trace when ``--profile-dir`` is set, else no-op.
 * :func:`annotate` — host-side ``TraceAnnotation`` span (PS fold, decode
-  step) visible on the trace timeline.
-* :func:`named_scope` — ``jax.named_scope`` for *traced* code (chunk scan,
-  ψ push, accelerate subproblem): pure metadata on the jaxpr, zero
-  runtime cost, so it is safe inside the fused hot path.
+  step, the phases of the launcher's chunk loop) visible on the trace
+  timeline.
+* :func:`named_scope` — ``jax.named_scope`` for *traced* code: pure
+  metadata on the jaxpr (it reaches each compiled op's ``op_name``, and the
+  device trace carries it as the op's ``tf_op``), zero runtime cost, so it
+  is safe inside the fused hot path.
+
+The scopes the program puts on traced ops and the host spans of the
+launcher's chunk loop are listed once, below; the program names them only
+through these constants.
 """
 from __future__ import annotations
 
@@ -118,6 +124,24 @@ class StepTimer:
 
 # ------------------------------------------------------------- profiler
 
+# scopes on traced ops (``named_scope``)
+CHUNK_SCAN = "obs/chunk_scan"    # the fused K-step scan
+ATTN = "obs/attn"                # attention sub-block: ln1, mixer, residual
+SSM = "obs/ssm"                  # the same for an SSM mixer
+MLP = "obs/mlp"                  # MLP sub-block: ln2, mlp, residual
+LM_HEAD = "obs/lm_head"          # final norm, head and loss
+UPDATE = "obs/update"            # the base rule's apply
+PSI_PUSH = "obs/psi_push"        # SPC queue push and control limit
+ACCELERATE = "obs/accelerate"    # the conservative subproblem
+
+# host spans of the launcher's chunk loop (``annotate``)
+TRAIN_DISPATCH = "train/dispatch"      # the chunk call
+TRAIN_FETCH = "train/fetch"            # device_get of the chunk's metrics
+TRAIN_OBS = "train/obs"                # obs ingest of those metrics
+TRAIN_LOG = "train/log"                # the per-chunk print
+TRAIN_CHECKPOINT = "train/checkpoint"  # maybe_save
+
+
 @contextlib.contextmanager
 def maybe_profile(profile_dir: Optional[str]):
     """Capture a ``jax.profiler`` trace into ``profile_dir`` when set
@@ -142,7 +166,7 @@ def annotate(name: str):
 
 
 def named_scope(name: str):
-    """``jax.named_scope`` — name traced operations (chunk scan, ψ push,
-    accelerate subproblem) on profiles/HLO at zero runtime cost."""
+    """``jax.named_scope`` — name traced operations (the scopes above) on
+    profiles/HLO at zero runtime cost."""
     import jax
     return jax.named_scope(name)

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.timing import CHUNK_SCAN, named_scope
+
 
 def selection_counts(batch_idx, n_batches: int) -> np.ndarray:
     """Visit histogram over batches from a realized ``batch_idx`` sequence
@@ -92,7 +94,7 @@ def chunk_over_schedule(step_fn: Callable, schedule, n_batches: int,
                 state, params, sched_state, ring_arrays, j0 + off)
             return (state, params, sched_state), metrics
 
-        with jax.named_scope("obs/chunk_scan"):
+        with named_scope(CHUNK_SCAN):
             (state, params, sched_state), stacked = jax.lax.scan(
                 scan_body, (state, params, sched_state),
                 jnp.arange(chunk_steps, dtype=jnp.int32))

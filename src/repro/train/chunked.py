@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core import ISGDConfig
 from repro.core.reduce import LOCAL, ReduceCtx
+from repro.obs.timing import CHUNK_SCAN, named_scope
 from repro.optim.base import UpdateRule
 from repro.train.trainer import make_step_core
 
@@ -59,7 +60,7 @@ def chunk_over_ring(step_fn: Callable, n_batches: int, chunk_steps: int):
             state, params, metrics = step_fn(state, params, batch)
             return (state, params), metrics
 
-        with jax.named_scope("obs/chunk_scan"):
+        with named_scope(CHUNK_SCAN):
             (state, params), stacked = jax.lax.scan(
                 body, (state, params),
                 jnp.arange(chunk_steps, dtype=jnp.int32))
