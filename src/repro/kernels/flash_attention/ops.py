@@ -2,7 +2,7 @@
 
 Maps (B, S, H, hd) q and (B, S, K, hd) k/v onto the kernel's flattened
 (B·H, S, hd) layout; the shared KV head of each query-head group is
-expanded with a gather (broadcast, no HBM copy under XLA).
+repeated per query head (``jnp.repeat``, a copy of K and V in HBM).
 
 ``gqa_flash`` is trainable: the forward runs the Pallas kernel, the
 backward is the standard softmax-attention gradient obtained by
@@ -42,9 +42,10 @@ def _flash_bwd(causal, window, bq, bk, interpret, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def gqa_flash(q, k, v, *, interpret: bool, causal=True, window=None, bq=128,
-              bk=128):
+def gqa_flash(q, k, v, *, interpret: bool, causal=True, window=None, bq=None,
+              bk=None):
     """q: (B, Sq, H, hd); k/v: (B, Sk, K, hd) -> (B, Sq, H, hd).
+    ``bq``/``bk`` default to the kernel's choice for the shape.
     ``interpret`` runs the kernel through the Pallas interpreter instead of
     Mosaic."""
     B, Sq, H, hd = q.shape

@@ -49,6 +49,8 @@ ATTN_SHAPES = [
     (8, 64, 16, True, None),    # paper-transformer-tiny (B·H=8, S=64, hd=16)
     (2, 192, 32, True, 64),     # seq not 128-aligned
     (1, 128, 32, False, None),  # non-causal (encoder/cross)
+    (2, 2048, 32, True, None),  # the default 1024 tiles leave a dead tile
+    (1, 4096, 32, True, 1024),  # ... on both sides of a window
 ]
 
 # ssd_scan: (b, S, nh, hd, G, ds, chunk)

@@ -24,3 +24,17 @@ def divisor_tile(n: int, want: int, align: int = 128) -> int:
     while n % b:
         b -= 1
     return b
+
+
+def flash_tiles(Sq: int, Sk: int, hd: int) -> tuple[int, int]:
+    """Default (bq, bk) of the flash attention kernel for a shape.
+
+    Square tiles of 1024 for head dims up to 128, halved for each doubling
+    of the head dim past that: the largest a f32 tile body (the (bq, bk)
+    scores and probabilities, the (bq, hd) accumulator) fits in the TPU's
+    default scoped VMEM.  On one v5e at B·H=32, S=4096, hd=128, bf16, 1024²
+    tiles ran a causal call in 1.45 ms against 2.49 ms at 512² and 5.28 ms
+    at 256² (per-call sweep, PERF.md §5).
+    """
+    want = max(128, 1024 * 128 // max(hd, 128))
+    return divisor_tile(Sq, want), divisor_tile(Sk, want)

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import attention_ref, flash_attention
+from repro.kernels.flash_attention.kernel import live_tiles
 from repro.kernels.flash_attention.ops import gqa_flash, gqa_ref
 from repro.kernels.fused_xent import fused_xent, xent_ref
 from repro.kernels.ssd_scan import ssd_chunked_pallas, ssd_ref
@@ -63,6 +64,56 @@ def test_flash_attention_sweep(BH, S, hd, causal, window, dtype):
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("BH,S,causal,window,bq,bk", [
+    (2, 512, True, None, 64, 64),
+    (1, 1024, True, None, 64, 64),
+    (1, 1024, True, 128, 64, 64),     # window: dead tiles on both sides
+    (2, 512, True, None, 128, 64),
+    (2, 512, True, None, 64, 128),
+    (2, 512, True, 96, 128, 64),      # window edge inside a tile, bq != bk
+    (2, 256, False, None, 64, 64),    # every tile live
+    (2, 512, False, 128, 64, 64),     # window alone: dead tiles on the left
+    (2, 192, True, None, 64, 64),     # ragged: 3 tiles of 64
+])
+def test_flash_attention_skips_dead_tiles(BH, S, causal, window, bq, bk):
+    """Tiles the mask kills are skipped; live tiles wholly inside the mask
+    skip the mask; the result is the oracle's."""
+    hd = 32
+    q, k, v = (jax.random.normal(jax.random.fold_in(KEY, i), (BH, S, hd))
+               for i in range(3))
+    out = flash_attention(q, k, v, causal=causal, window=window, bq=bq, bk=bk,
+                          interpret=True)
+    ref = attention_ref(q, k, v, causal=causal, window=window)
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_live_tiles_pins():
+    assert live_tiles(4096, 4096, 512, 512, True, None) == 36
+    assert live_tiles(4096, 4096, 128, 128, True, None) == 528
+    # window 128 of 64-tiles: the diagonal tile and the two before it
+    assert live_tiles(1024, 1024, 64, 64, True, 128) == 1 + 2 + 14 * 3
+    assert live_tiles(4096, 4096, 512, 512, False, None) == 64
+    assert live_tiles(4096, 4096, 512, 1024, False, None) == 32
+
+
+@pytest.mark.parametrize("S,bq,bk,causal,window", [
+    (512, 64, 64, True, None), (512, 128, 64, True, 96),
+    (512, 64, 128, True, 200), (512, 64, 64, False, 128),
+    (384, 128, 64, True, 64), (256, 64, 64, False, None),
+])
+def test_live_tiles_matches_mask(S, bq, bk, causal, window):
+    """live_tiles counts exactly the tile pairs holding an unmasked entry."""
+    qpos = np.arange(S)[:, None]
+    kpos = np.arange(S)[None, :]
+    mask = np.ones((S, S), bool)
+    if causal:
+        mask &= kpos <= qpos
+    if window is not None:
+        mask &= kpos > qpos - window
+    alive = mask.reshape(S // bq, bq, S // bk, bk).any(axis=(1, 3))
+    assert live_tiles(S, S, bq, bk, causal, window) == int(alive.sum())
 
 
 def test_gqa_wrapper_matches_ref():

@@ -57,12 +57,15 @@ def _assert_mosaic(fn, args):
 # paper-transformer base tier (B=8, S=1024): 16 heads, head_dim 64, d 1024,
 # vocab 32768; paper-ssm base tier (B=8, S=1024): 32 heads of 64, state 128,
 # chunk 256, one group.
+# flash_attention_cell: the benchmark's InternLM2 cell (B=2 x 16 heads,
+# S=4096, head_dim 128) at the tiles the kernel chooses for the shape.
 @pytest.mark.parametrize("kernel", ["flash_attention", "fused_xent",
-                                    "ssd_intra_chunk"])
+                                    "ssd_intra_chunk", "flash_attention_cell"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
-    if kernel == "flash_attention":
-        qkv = ((128, 1024, 64), bf16)
+    if kernel.startswith("flash_attention"):
+        qkv = ((128, 1024, 64) if kernel == "flash_attention"
+               else (32, 4096, 128), bf16)
         _assert_mosaic(lambda q, k, v: flash_attention(q, k, v, causal=True,
                                                        interpret=False),
                        _specs(one_chip, qkv, qkv, qkv))
