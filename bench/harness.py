@@ -24,6 +24,8 @@ from pathlib import Path
 
 import numpy as np
 
+from bench import trace as TR
+
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 
@@ -323,6 +325,32 @@ class Program:
                 "update": np.asarray(self.norms(call.params, p0))}
         return state, j, first, prog
 
+    def trip_chunk(self, seed, start):
+        """The program replayed from the seed through the window's own call
+        to ``start`` (a chunk boundary), as the run drove it, then one chunk
+        more.  -> (params and momentum at ``start`` on the host, the
+        program's update norms over that chunk, the replay's chunks)."""
+        import jax
+
+        from repro.launch.train import _drive_chunks
+        k = self.cell.traffic["chunk_steps"]
+        call, state, ring, _, _ = self.start(seed)
+        state, _, chunks = _drive_chunks(call, state, call.params, ring,
+                                         start, k)
+        host = jax.device_get((call.params, state.base))
+        state, _, more = _drive_chunks(call, state, call.params, ring,
+                                       start + k, k, start=start)
+        base = jax.device_put(host[0], self.rep)
+        update = np.asarray(self.norms(call.params, base))
+        return host, update, chunks + more
+
+
+def series_of(chunks):
+    """Per-step controller metrics of ``chunks``, concatenated."""
+    return {key: np.concatenate([np.asarray(c["metrics"][key])
+                                 for c in chunks])
+            for key in ("loss", "limit", "accelerated", "sub_iters")}
+
 
 def setup_cache():
     import jax
@@ -337,7 +365,10 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         t_start: float, require_chip: bool = True, kernels: str = "pallas",
         loss_wrap=None, keep_trace=None, log=print) -> dict:
     """Set-up, window (or traced epoch), then the comparison.  Returns the
-    result object of the contract.  ``loss_wrap`` (the fault tests) wraps
+    result object of the contract.  Untraced, ``tokens_per_s`` is the
+    tokens through the ISGD step's forward+backward passes per second: each
+    main step and each subproblem trip on its batch (``trace.passes``) over the
+    window's wall time; tracing off.  ``loss_wrap`` (the fault tests) wraps
     the program's loss before the engine is built; ``keep_trace`` (the
     trace recorder of the self-tests) is a path that keeps the traced
     run's ``.xplane.pb``."""
@@ -397,20 +428,31 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         steps = sum(len(c["metrics"]["loss"]) for c in win)
         trips = sum(int(np.sum(c["metrics"]["sub_iters"])) for c in win)
         del state, call, ring
-    series = {key_: np.concatenate([np.asarray(c["metrics"][key_])
-                                    for c in chunks])
-              for key_ in ("loss", "limit", "accelerated", "sub_iters")}
-    log(f"window: {steps} steps, {trips} subproblem trips; accelerated at "
-        f"steps {np.flatnonzero(series['accelerated']).tolist()} of "
-        f"{len(series['loss'])} (window from step {len(series['loss']) - steps})")
+        series = series_of(chunks)
+        w_start = len(series["loss"]) - steps
+        log(f"window: {steps} steps, {trips} subproblem trips; accelerated "
+            f"at steps {np.flatnonzero(series['accelerated']).tolist()} of "
+            f"{len(series['loss'])} (window from step {w_start})")
+        trip = None
+        fire = compare.first_firing(series, w_start)
+        if fire is not None:
+            start = fire - fire % k
+            host, update, replay = prog_.trip_chunk(seed, start)
+            drift = np.abs(series_of(replay)["loss"]
+                           - series["loss"][:start + k])
+            log(f"replayed steps 0-{start + k - 1} from the seed: largest "
+                f"loss change against the run {float(drift.max()):.3e}")
+            trip = (start, host, update)
+            del host
 
     numbers = compare.compare(cell, seed, epoch, prog, prog_.names, series,
-                              log=log)
+                              trip, log=log)
+    del trip
     correct = all(v["value"] <= v["limit"] for v in numbers.values())
     result = {"correct": bool(correct), "attempted": steps,
-              "failed": 0 if correct else steps, "trips": trips}
+              "failed": 0 if correct else steps, "trips": trips,
+              "window_s": window_s}
     if trace:
-        from bench import trace as TR
         if keep_trace:
             shutil.copy(TR.xplane_path(trace_dir), keep_trace)
         tdata = TR.load(trace_dir, len(devices))
@@ -429,7 +471,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = TR.breakdown(tdata)
     else:
         result["metrics"] = {
-            "tokens_per_s": {"value": steps * cell.tokens_per_step / window_s,
+            "tokens_per_s": {"value": TR.passes(steps, trips)
+                             * cell.tokens_per_step / window_s,
                              "unit": "tokens/s"},
             "setup_s": {"value": setup_s, "unit": "s"}}
         result["device"] = dict(device_info(devices),

@@ -1,9 +1,10 @@
 """mfu (%): the forward and backward FLOPs that every pass of the traced
 window requires (each ISGD step's main pass and each subproblem trip, on
-the global batch), over window x chips x the bf16 peak.  Per token a pass
-needs 3 x the forward: 2 x the matmul weights it meets, plus causal
-attention (dense family) or the SSD scan (ssm family).  Remat's recompute,
-the embedding lookup and elementwise work are not counted."""
+the global batch, counted by ``bench/trace.py::passes``), over window x
+chips x the bf16 peak.  Per token a pass needs 3 x the forward: 2 x the
+matmul weights it meets, plus causal attention (dense family) or the SSD
+scan (ssm family).  Remat's recompute, the embedding lookup and
+elementwise work are not counted."""
 
 
 def dense_forward_flops_per_token(m: dict, seq: int) -> float:
@@ -36,6 +37,6 @@ def pass_flops(model: dict, family: str, batch: int, seq: int) -> float:
 def read(ctx):
     cell = ctx.cell
     flops = pass_flops(ctx.model, cell.config["family"], cell.global_batch,
-                       ctx.traffic["seq_len"]) * (ctx.steps + ctx.trips)
+                       ctx.traffic["seq_len"]) * ctx.passes
     peak = ctx.trace.window_s * cell.chips * ctx.peaks["bf16_flops"]
     return 100.0 * flops / peak

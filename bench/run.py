@@ -7,10 +7,12 @@ as the last line of standard output, one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
 ``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``,
 ``breakdown`` (traced runs) and, last, ``compared``: each number of the
-comparison with the plain reference beside its limit.  The same numbers
-are the last lines of standard error.  With no TPU, fewer chips than the
-cell asks for, or a device kind missing from ``bench/peaks.json``, it exits
-2 and prints no result.
+comparison with the plain reference beside its limit.  ``tokens_per_s`` is
+the tokens through the ISGD step's forward+backward passes per second: each
+main step and each subproblem trip on its batch; tracing off.  The same
+numbers are the last lines of standard error.  With no TPU, fewer chips
+than the cell asks for, or a device kind missing from ``bench/peaks.json``,
+it exits 2 and prints no result.
 """
 import time
 

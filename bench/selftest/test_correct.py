@@ -1,27 +1,33 @@
 """``correct`` comes out false when the timed path is broken underneath,
 and true when it is not: the harness's run at a size a test run can hold,
-past its look for a chip, against each cell's own limits
-(``bench/limits/<cell>.json``).  The control (the reference in fp8 in the
-program's place) fails the same limits."""
+past its look for a chip, against the cell's limits set at that size by
+the rule of its chip limits (``bench/limits/<cell>.tiny.json``).  The
+control (the reference in fp8 in the program's place) fails the same
+limits."""
 import time
 
 import numpy as np
 import pytest
 
-from bench import compare
+from bench import calibrate, compare
 from bench import harness as H
-from bench.selftest.tiny import tiny_cell
+from bench.selftest.tiny import FIRING, tiny_cell
 
 CELLS = [w["name"] for w in H.load_json(H.ROOT / "BENCHMARK.json")["workloads"]]
 
 
 def limits_of(name):
-    return H.load_json(H.BENCH / "limits" / f"{name}.json")["limits"]
+    """The cell's limits at the tiny size, set by the rule of its chip
+    limits from tiny readings (``bench/calibrate.py --tiny``)."""
+    return H.load_json(H.BENCH / "limits" / f"{name}.tiny.json")["limits"]
 
 
-def run(name, loss_wrap=None, monkeypatch=None, patch=None):
+def run(name, loss_wrap=None, monkeypatch=None, patch=None, isgd=None):
+    """The harness's run of the tiny cell ``name``; ``isgd`` overrides the
+    configuration's ISGD settings."""
     cell = tiny_cell(name, chips=H.find_cell(name).chips,
                      limits=limits_of(name))
+    cell.config["isgd"].update(isgd or {})
     if patch is not None:
         patch(monkeypatch)
     res = H.run(cell, 2**31 + 7, 0.5, False, t_start=time.perf_counter(),
@@ -47,6 +53,10 @@ def loss_altered(loss_fn):
     return f
 
 
+def subproblem_unchanged(monkeypatch):
+    calibrate.subproblem_unchanged(monkeypatch.setattr)
+
+
 def no_exchange(monkeypatch):
     from repro.core.reduce import LOCAL
     from repro.distributed import data_parallel
@@ -58,6 +68,28 @@ def no_exchange(monkeypatch):
 def test_sound_run_is_correct(name):
     res = run(name)
     assert res["correct"], res["compared"]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_tokens_per_s_counts_subproblem_trips(name):
+    """Each trip counts as one pass over the global batch, as in ``mfu``, and
+    the subproblem is compared with the reference's."""
+    res = run(name, isgd=FIRING)
+    assert res["trips"] > 0
+    assert res["correct"], res["compared"]
+    assert "trip_loss_gap" in res["compared"]
+    cell = tiny_cell(name, chips=H.find_cell(name).chips)
+    assert res["metrics"]["tokens_per_s"]["value"] == pytest.approx(
+        (res["attempted"] + res["trips"]) * cell.tokens_per_step
+        / res["window_s"], rel=1e-12)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_subproblem_unchanged_is_not_correct(name, monkeypatch):
+    res = run(name, monkeypatch=monkeypatch, patch=subproblem_unchanged,
+              isgd=FIRING)
+    assert res["trips"] > 0
+    assert not res["correct"], res["compared"]
 
 
 @pytest.mark.parametrize("name", CELLS)

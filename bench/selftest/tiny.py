@@ -13,6 +13,9 @@ TINY_MODEL = {
     "mamba2": {"num_layers": 2, "d_model": 64, "vocab_size": 500,
                "ssm_state": 16, "ssm_headdim": 16, "ssm_chunk": 16},
 }
+#: the SPC limit a standard deviation below the mean: the controller fires
+#: in a tiny window, so its trips are there to count and to compare
+FIRING = {"k_sigma": -1.0}
 TINY_TRAFFIC = {"n_batches": 4, "batch_per_chip": 2, "seq_len": 64,
                 "chunk_steps": 2, "warmup_epochs": 1,
                 "markov": {"n_ctx": 64, "branch": 4, "p_follow": 0.8}}

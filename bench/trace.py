@@ -173,6 +173,13 @@ def breakdown(t: TraceData) -> dict:
     return {"device_ops": [[k, v] for k, v in top], "idle_gaps": gaps}
 
 
+def passes(steps: int, trips: int) -> int:
+    """Forward+backward passes over the global batch: each ISGD step's main
+    pass and each subproblem trip (one pass on its step's batch).  The one
+    count behind both ``tokens_per_s`` and ``mfu``."""
+    return steps + trips
+
+
 @dataclasses.dataclass
 class Context:
     """What a per-layer reader gets: the cell (its configuration and
@@ -183,6 +190,10 @@ class Context:
     trace: TraceData
     steps: int
     trips: int
+
+    @property
+    def passes(self) -> int:
+        return passes(self.steps, self.trips)
 
     @property
     def model(self) -> dict:
