@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.analysis.mode import scan_unroll
 from repro.models.layers import dense_init, rms_norm
+from repro.obs.timing import SSD, named_scope
 
 
 def conv_channels(cfg) -> int:
@@ -54,12 +55,17 @@ def _causal_conv(xBC, w, b):
 
 
 def _segsum(dAh):
-    """dAh: (..., cl) cumulative-decay matrix L[i,j] = exp(Σ_{j<m<=i} dA_m), i>=j."""
+    """dAh: (..., cl) cumulative-decay matrix L[i,j] = exp(Σ_{j<m<=i} dA_m), i>=j.
+
+    The exponent is masked to -inf above the diagonal *before* the exp: there
+    it is +Σ|dA|, which overflows over a long chunk at published decays, and
+    ``where(mask, exp(diff), 0)`` would then pass 0 · inf = NaN into the
+    gradient."""
     cl = dAh.shape[-1]
     cum = jnp.cumsum(dAh, axis=-1)
     diff = cum[..., :, None] - cum[..., None, :]
     mask = jnp.tril(jnp.ones((cl, cl), bool))
-    return jnp.where(mask, jnp.exp(diff), 0.0)
+    return jnp.exp(jnp.where(mask, diff, -jnp.inf))
 
 
 def ssd_chunked(x, dt, A, B, C, chunk: int, initial_state=None):
@@ -136,12 +142,14 @@ def ssm_forward(params, cfg, x, kernels: str = "reference"):
     Cm = xBC[..., di + G * ds:].reshape(b, S, G, ds)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["A_log"])
-    if kernels != "reference":
-        from repro.kernels.ssd_scan.ops import ssd_chunked_pallas
-        y, ssd_state = ssd_chunked_pallas(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk,
-                                          interpret=kernels == "interpret")
-    else:
-        y, ssd_state = ssd_chunked(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
+    with named_scope(SSD):
+        if kernels != "reference":
+            from repro.kernels.ssd_scan.ops import ssd_chunked_pallas
+            y, ssd_state = ssd_chunked_pallas(xs, dt, A, Bm, Cm,
+                                              chunk=cfg.ssm_chunk,
+                                              interpret=kernels == "interpret")
+        else:
+            y, ssd_state = ssd_chunked(xs, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
     y = y + params["D"][:, None] * xs.astype(jnp.float32)
     y = y.reshape(b, S, di).astype(x.dtype)
     y = rms_norm(y * jax.nn.silu(z), params["gnorm"], cfg.norm_eps)

@@ -128,6 +128,7 @@ class StepTimer:
 CHUNK_SCAN = "obs/chunk_scan"    # the fused K-step scan
 ATTN = "obs/attn"                # attention sub-block: ln1, mixer, residual
 SSM = "obs/ssm"                  # the same for an SSM mixer
+SSD = "obs/ssd"                  # its chunked SSD scan, inside SSM
 MLP = "obs/mlp"                  # MLP sub-block: ln2, mlp, residual
 LM_HEAD = "obs/lm_head"          # final norm, head and loss
 UPDATE = "obs/update"            # the base rule's apply

@@ -173,6 +173,22 @@ def test_ssd_chunking_invariance():
     np.testing.assert_allclose(s16, s64, rtol=1e-4, atol=1e-4)
 
 
+def _ssd_naive(x, dt, A, B, C):
+    """The step-by-step SSM recurrence (one group), in jnp so that
+    ``jax.grad`` runs through it."""
+    def step(state, inp):
+        x_t, dt_t, B_t, C_t = inp          # (b,nh,hd), (b,nh), (b,ds), (b,ds)
+        state = (state * jnp.exp(dt_t * A)[..., None, None]
+                 + jnp.einsum("bhp,bd->bhpd", x_t * dt_t[..., None], B_t))
+        return state, jnp.einsum("bhpd,bd->bhp", state, C_t)
+
+    b, _, nh, hd = x.shape
+    init = jnp.zeros((b, nh, hd, B.shape[-1]))
+    final, ys = jax.lax.scan(step, init, tuple(
+        jnp.moveaxis(a, 1, 0) for a in (x, dt, B[:, :, 0], C[:, :, 0])))
+    return jnp.moveaxis(ys, 0, 1), final
+
+
 def test_ssd_matches_naive_recurrence():
     """Oracle of the oracle: step-by-step SSM recurrence."""
     b, S, nh, hd, ds = 1, 32, 2, 8, 4
@@ -182,18 +198,46 @@ def test_ssd_matches_naive_recurrence():
     B = jax.random.normal(jax.random.fold_in(KEY, 3), (b, S, 1, ds))
     C = jax.random.normal(jax.random.fold_in(KEY, 4), (b, S, 1, ds))
     y_ref, s_ref = ssd_ref(x, dt, A, B, C, chunk=8)
-
-    state = np.zeros((b, nh, hd, ds))
-    ys = []
-    for t in range(S):
-        decay = np.exp(np.asarray(dt[:, t]) * np.asarray(A))      # (b, nh)
-        xdt = np.asarray(x[:, t]) * np.asarray(dt[:, t])[..., None]
-        state = state * decay[..., None, None] + np.einsum(
-            "bhp,bd->bhpd", xdt, np.asarray(B[:, t, 0]))
-        ys.append(np.einsum("bhpd,bd->bhp", state, np.asarray(C[:, t, 0])))
-    y_naive = np.stack(ys, axis=1)
+    y_naive, state = _ssd_naive(x, dt, A, B, C)
     np.testing.assert_allclose(y_ref, y_naive, rtol=1e-3, atol=1e-3)
     np.testing.assert_allclose(s_ref, state, rtol=1e-3, atol=1e-3)
+
+
+@pytest.mark.parametrize("impl", ["reference", "pallas_interpret"])
+def test_ssd_grads_finite_at_published_decay(impl):
+    """Gradients of x, dt, A, B and C at Mamba2's published decay: dt 0.1
+    and A = -16 over a 64-long chunk put +Σ|dt A| ≈ 102 above the
+    diagonal of the segment sum, past f32's exp range, so the mask has to
+    come before the exp."""
+    b, S, nh, hd, ds = 1, 64, 2, 16, 8
+    x = jax.random.normal(KEY, (b, S, nh, hd))
+    dt = jnp.full((b, S, nh), 0.1)
+    A = jnp.array([-16.0, -1.0])
+    B = jax.random.normal(jax.random.fold_in(KEY, 3), (b, S, 1, ds))
+    C = jax.random.normal(jax.random.fold_in(KEY, 4), (b, S, 1, ds))
+    wy = jax.random.normal(jax.random.fold_in(KEY, 5), (b, S, nh, hd))
+    ws = jax.random.normal(jax.random.fold_in(KEY, 6), (b, nh, hd, ds))
+    ssd = {"reference": lambda *a: ssd_ref(*a, chunk=S),
+           "pallas_interpret": lambda *a: ssd_chunked_pallas(
+               *a, chunk=S, interpret=True)}[impl]
+
+    def loss(f):
+        def g(*args):
+            y, s = f(*args)
+            return jnp.sum(y * wy) + jnp.sum(s * ws)
+        return jax.grad(g, argnums=(0, 1, 2, 3, 4))
+
+    got = loss(ssd)(x, dt, A, B, C)
+    want = loss(_ssd_naive)(x, dt, A, B, C)
+    for name, g, w in zip("x dt A B C".split(), got, want):
+        assert np.isfinite(g).all(), name
+        # f32 both ways, summed in different orders (a chunk's decays as
+        # exp of cumsum differences, the recurrence as a running product):
+        # round-off of a few ulp of the largest term, relative to the
+        # gradient's own scale
+        np.testing.assert_allclose(g, w, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(w))),
+                                   err_msg=name)
 
 
 def test_fused_xent_custom_vjp_matches_ref():

@@ -114,6 +114,20 @@ def test_layer_scopes_reach_op_name_in_forward_and_backward(program):
         assert not _with(names, "obs/attn") and not _with(names, "obs/mlp")
 
 
+def test_ssd_scope_nests_inside_ssm_scope(program):
+    """The SSD scan's own scope, inside the mixer's, in the forward, the
+    backward and remat's recompute; a dense program has none."""
+    ssd = _with(program.names, "obs/ssd")
+    if program.family == "dense":
+        assert not ssd
+        return
+    assert all(re.search(r"(^|[/(])obs/ssm/(.*/)?obs/ssd([/)]|$)", n)
+               for n in ssd), [n for n in ssd if "obs/ssm/" not in n]
+    assert [n for n in ssd if "transpose(" not in n], "no forward op"
+    assert any("transpose(" in n for n in ssd), "no backward op"
+    assert any("rematted_computation/" in n for n in ssd), "no recompute op"
+
+
 def test_scope_forms_under_jvp_transpose_and_remat(program):
     """The exact forms the readers match: layer scopes inside the scan over
     layers stay plain after ``jvp()`` / ``transpose(jvp())`` and, in the
