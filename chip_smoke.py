@@ -135,6 +135,7 @@ def phase_kernels() -> None:
     check(math.isfinite(losses["pallas"]), "non-finite pallas loss")
     check(diff <= bound, f"pallas loss off reference by {diff:.3e}")
     cases = [("flash_attention", (128, 1024, 64, True, None)),
+             ("flash_attention_bwd", (128, 1024, 64, True, None)),
              ("fused_xent", (8192, 1024, 32768, 32768))]
     for kernel, shape in cases:
         rep = check_case(kernel, "bfloat16", shape, interpret=False)

@@ -4,11 +4,11 @@ Maps (B, S, H, hd) q and (B, S, K, hd) k/v onto the kernel's flattened
 (B·H, S, hd) layout; the shared KV head of each query-head group is
 repeated per query head (``jnp.repeat``, a copy of K and V in HBM).
 
-``gqa_flash`` is trainable: the forward runs the Pallas kernel, the
-backward is the standard softmax-attention gradient obtained by
-differentiating the oracle (recompute-from-inputs — exactly what a flash
-backward does; the fused TPU bwd kernel is a follow-up, mirroring
-fused_xent's split).
+``gqa_flash`` is trainable: the forward runs the Pallas kernel and saves
+``(q, k, v, o, lse)``; the backward is the Pallas kernel
+``flash_attention_bwd``, which recomputes the probabilities tile by tile
+from the saved log-sum-exp.  The vjp of the K/V repeat sums dK/dV over
+each query-head group.
 """
 from __future__ import annotations
 
@@ -17,7 +17,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.flash_attention.kernel import flash_attention
+from repro.kernels.flash_attention.kernel import (flash_attention,
+                                                  flash_attention_bwd)
 from repro.kernels.flash_attention.ref import attention_ref
 
 
@@ -28,15 +29,16 @@ def _flash(q, k, v, causal, window, bq, bk, interpret):
 
 
 def _flash_fwd(q, k, v, causal, window, bq, bk, interpret):
-    return _flash(q, k, v, causal, window, bq, bk, interpret), (q, k, v)
+    o, lse = flash_attention(q, k, v, causal=causal, window=window, bq=bq,
+                             bk=bk, interpret=interpret, save_lse=True)
+    return o, (q, k, v, o, lse)
 
 
 def _flash_bwd(causal, window, bq, bk, interpret, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: attention_ref(q_, k_, v_, causal=causal,
-                                         window=window), q, k, v)
-    return vjp(g)
+    q, k, v, o, lse = res
+    return flash_attention_bwd(q, k, v, o, lse, g, causal=causal,
+                               window=window, bq=bq, bk=bk,
+                               interpret=interpret)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)

@@ -30,6 +30,7 @@ import argparse
 TOLERANCES = {
     "fused_xent": {"float32": (1e-4, 1e-4), "bfloat16": (2e-2, 2e-2)},
     "flash_attention": {"float32": (2e-5, 2e-5), "bfloat16": (3e-2, 3e-2)},
+    "flash_attention_bwd": {"float32": (2e-5, 2e-5), "bfloat16": (3e-2, 3e-2)},
     "ssd_scan": {"float32": (1e-3, 1e-3), "bfloat16": (3e-2, 3e-2)},
 }
 
@@ -42,7 +43,8 @@ XENT_SHAPES = [
     (128, 64, 256, 256),      # paper-transformer-tiny head (d=64, V=256)
 ]
 
-# flash_attention: (BH, S, hd, causal, window)
+# flash_attention and its backward (dq, dk, dv against the oracle's vjp):
+# (BH, S, hd, causal, window)
 ATTN_SHAPES = [
     (4, 256, 64, True, None),
     (2, 256, 64, True, 64),     # sliding window
@@ -70,6 +72,7 @@ def iter_cases():
             yield ("fused_xent", dt, shp)
         for shp in ATTN_SHAPES:
             yield ("flash_attention", dt, shp)
+            yield ("flash_attention_bwd", dt, shp)
         for shp in SSD_SHAPES:
             yield ("ssd_scan", dt, shp)
 
@@ -98,16 +101,26 @@ def check_case(kernel: str, dtype_name: str, shape, *,
         out = fused_xent(h, w, y, vocab_size=V, interpret=interpret)
         ref = xent_ref(h, w, y, vocab_size=V)
         outs, refs = [out], [ref]
-    elif kernel == "flash_attention":
-        from repro.kernels.flash_attention import attention_ref, flash_attention
+    elif kernel.startswith("flash_attention"):
+        from repro.kernels.flash_attention import (attention_ref,
+                                                   flash_attention, gqa_flash)
         BH, S, hd, causal, window = shape
         q = jax.random.normal(key, (BH, S, hd), jnp.float32).astype(dtype)
         k = jax.random.normal(sub(1), (BH, S, hd), jnp.float32).astype(dtype)
         v = jax.random.normal(sub(2), (BH, S, hd), jnp.float32).astype(dtype)
-        out = flash_attention(q, k, v, causal=causal, window=window,
-                              interpret=interpret)
-        ref = attention_ref(q, k, v, causal=causal, window=window)
-        outs, refs = [out], [ref]
+        if kernel == "flash_attention":
+            outs = [flash_attention(q, k, v, causal=causal, window=window,
+                                    interpret=interpret)]
+            refs = [attention_ref(q, k, v, causal=causal, window=window)]
+        else:
+            g = jax.random.normal(sub(3), (BH, S, hd), jnp.float32).astype(dtype)
+            # BH sequences of one head each through the trainable wrapper
+            outs = jax.vjp(lambda *a: gqa_flash(
+                *(x[:, :, None] for x in a), causal=causal, window=window,
+                interpret=interpret)[:, :, 0], q, k, v)[1](g)
+            refs = jax.vjp(lambda *a: attention_ref(*a, causal=causal,
+                                                    window=window),
+                           q, k, v)[1](g)
     else:
         from repro.kernels.ssd_scan import ssd_chunked_pallas, ssd_ref
         b, S, nh, hd, G, ds, chunk = shape

@@ -38,3 +38,18 @@ def flash_tiles(Sq: int, Sk: int, hd: int) -> tuple[int, int]:
     """
     want = max(128, 1024 * 128 // max(hd, 128))
     return divisor_tile(Sq, want), divisor_tile(Sk, want)
+
+
+def flash_bwd_tiles(Sq: int, Sk: int, hd: int) -> tuple[int, int]:
+    """Default (bq, bk) of the flash attention backward kernel for a shape.
+
+    Square tiles of 512 for head dims up to 128, halved for each doubling
+    of the head dim past that.  The tile body holds four f32 (bk, bq)
+    arrays (scores, probabilities, dP, dS), so 1024² tiles (16 MiB) need a
+    raised scoped-VMEM limit (``kernel.py::_bwd_vmem_limit``).  On one v5e
+    at B·H=32, S=4096, hd=128, bf16, causal, a call took 2.766 ms at 512²
+    against 2.756 at 1024², 2.780 at 512×1024 and 4.289 at 256² (per-call
+    sweep, PERF.md §5): 1024² does not win enough to raise the limit.
+    """
+    want = max(128, 512 * 128 // max(hd, 128))
+    return divisor_tile(Sq, want), divisor_tile(Sk, want)

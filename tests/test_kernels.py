@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.flash_attention import attention_ref, flash_attention
-from repro.kernels.flash_attention.kernel import live_tiles
+from repro.kernels.flash_attention.kernel import live_q_range, live_tiles
 from repro.kernels.flash_attention.ops import gqa_flash, gqa_ref
 from repro.kernels.fused_xent import fused_xent, xent_ref
 from repro.kernels.ssd_scan import ssd_chunked_pallas, ssd_ref
@@ -66,7 +66,7 @@ def test_flash_attention_sweep(BH, S, hd, causal, window, dtype):
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("BH,S,causal,window,bq,bk", [
+DEAD_TILE_SHAPES = [
     (2, 512, True, None, 64, 64),
     (1, 1024, True, None, 64, 64),
     (1, 1024, True, 128, 64, 64),     # window: dead tiles on both sides
@@ -76,7 +76,11 @@ def test_flash_attention_sweep(BH, S, hd, causal, window, dtype):
     (2, 256, False, None, 64, 64),    # every tile live
     (2, 512, False, 128, 64, 64),     # window alone: dead tiles on the left
     (2, 192, True, None, 64, 64),     # ragged: 3 tiles of 64
-])
+    (1, 512, True, 66, 64, 64),       # window reaches one row into a tile
+]
+
+
+@pytest.mark.parametrize("BH,S,causal,window,bq,bk", DEAD_TILE_SHAPES)
 def test_flash_attention_skips_dead_tiles(BH, S, causal, window, bq, bk):
     """Tiles the mask kills are skipped; live tiles wholly inside the mask
     skip the mask; the result is the oracle's."""
@@ -89,6 +93,32 @@ def test_flash_attention_skips_dead_tiles(BH, S, causal, window, bq, bk):
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("BH,S,causal,window,bq,bk,dtype", [
+    *(shape + (jnp.float32,) for shape in DEAD_TILE_SHAPES),
+    (2, 512, True, 96, 128, 64, jnp.bfloat16),
+])
+def test_flash_attention_grads_match_ref(BH, S, causal, window, bq, bk,
+                                         dtype):
+    """The backward kernel's dq, dk, dv are the oracle's vjp, with the
+    dead tiles of both sweeps skipped."""
+    hd = 32
+    q, k, v, g = (jax.random.normal(jax.random.fold_in(KEY, i), (BH, S, hd),
+                                    jnp.float32).astype(dtype)
+                  for i in range(4))
+    out = jax.vjp(lambda *a: gqa_flash(*(x[:, :, None] for x in a),
+                                       causal=causal, window=window, bq=bq,
+                                       bk=bk, interpret=True)[:, :, 0],
+                  q, k, v)[1](g)
+    ref = jax.vjp(lambda *a: attention_ref(*a, causal=causal, window=window),
+                  q, k, v)[1](g)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    for o, r in zip(out, ref):
+        assert o.dtype == dtype
+        np.testing.assert_allclose(np.asarray(o, np.float32),
+                                   np.asarray(r, np.float32),
+                                   rtol=tol, atol=tol)
+
+
 def test_live_tiles_pins():
     assert live_tiles(4096, 4096, 512, 512, True, None) == 36
     assert live_tiles(4096, 4096, 128, 128, True, None) == 528
@@ -98,13 +128,18 @@ def test_live_tiles_pins():
     assert live_tiles(4096, 4096, 512, 1024, False, None) == 32
 
 
-@pytest.mark.parametrize("S,bq,bk,causal,window", [
+# (S, bq, bk, causal, window); window 66 at 64-tiles reaches one row into
+# the second query tile after a key tile
+MASK_SHAPES = [
     (512, 64, 64, True, None), (512, 128, 64, True, 96),
     (512, 64, 128, True, 200), (512, 64, 64, False, 128),
     (384, 128, 64, True, 64), (256, 64, 64, False, None),
-])
-def test_live_tiles_matches_mask(S, bq, bk, causal, window):
-    """live_tiles counts exactly the tile pairs holding an unmasked entry."""
+    (512, 64, 64, True, 66),
+]
+
+
+def _alive(S, bq, bk, causal, window):
+    """(q tile, k tile) pairs holding an unmasked entry, from the dense mask."""
     qpos = np.arange(S)[:, None]
     kpos = np.arange(S)[None, :]
     mask = np.ones((S, S), bool)
@@ -112,8 +147,27 @@ def test_live_tiles_matches_mask(S, bq, bk, causal, window):
         mask &= kpos <= qpos
     if window is not None:
         mask &= kpos > qpos - window
-    alive = mask.reshape(S // bq, bq, S // bk, bk).any(axis=(1, 3))
+    return mask.reshape(S // bq, bq, S // bk, bk).any(axis=(1, 3))
+
+
+@pytest.mark.parametrize("S,bq,bk,causal,window", MASK_SHAPES)
+def test_live_tiles_matches_mask(S, bq, bk, causal, window):
+    """live_tiles counts exactly the tile pairs holding an unmasked entry."""
+    alive = _alive(S, bq, bk, causal, window)
     assert live_tiles(S, S, bq, bk, causal, window) == int(alive.sum())
+
+
+@pytest.mark.parametrize("S,bq,bk,causal,window", MASK_SHAPES)
+def test_live_q_range_matches_mask(S, bq, bk, causal, window):
+    """live_q_range gives each key tile exactly the query tiles holding an
+    unmasked entry, the first to the last with none dead between."""
+    alive = _alive(S, bq, bk, causal, window)
+    for j in range(S // bk):
+        first, last = live_q_range(j, bq=bq, bk=bk, nq=S // bq,
+                                   causal=causal, window=window)
+        live = np.flatnonzero(alive[:, j])
+        assert (int(first), int(last)) == (live[0], live[-1])
+        assert len(live) == live[-1] - live[0] + 1
 
 
 def test_gqa_wrapper_matches_ref():
@@ -124,6 +178,24 @@ def test_gqa_wrapper_matches_ref():
     out = gqa_flash(q, k, v, bq=64, bk=64, interpret=True)
     ref = gqa_ref(q, k, v)
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_gqa_flash_grads_match_ref():
+    """Grads through the GQA wrapper: the K/V repeat's vjp sums each
+    group's dK/dV from the backward kernel."""
+    B, S, H, K, hd = 2, 128, 8, 2, 32
+    q = jax.random.normal(KEY, (B, S, H, hd))
+    k = jax.random.normal(jax.random.fold_in(KEY, 1), (B, S, K, hd))
+    v = jax.random.normal(jax.random.fold_in(KEY, 2), (B, S, K, hd))
+
+    def loss(f):
+        return lambda q, k, v: jnp.sum(f(q, k, v) ** 2)
+    out = jax.grad(loss(lambda *a: gqa_flash(*a, bq=64, bk=64,
+                                             interpret=True)),
+                   argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(gqa_ref), argnums=(0, 1, 2))(q, k, v)
+    for o, r in zip(out, ref):
+        np.testing.assert_allclose(o, r, rtol=2e-5, atol=2e-5)
 
 
 def test_flash_matches_model_attention_path():

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, gqa_flash
 from repro.kernels.fused_xent import fused_xent
 from repro.kernels.ssd_scan import ssd_intra_chunk
 
@@ -52,18 +52,37 @@ def _specs(sharding, *shapes):
 def _assert_mosaic(fn, args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 # paper-transformer base tier (B=8, S=1024): 16 heads, head_dim 64, d 1024,
 # vocab 32768; paper-ssm base tier (B=8, S=1024): 32 heads of 64, state 128,
 # chunk 256, one group.
 # flash_attention_cell: the benchmark's InternLM2 cell (B=2 x 16 heads,
-# S=4096, head_dim 128) at the tiles the kernel chooses for the shape.
+# S=4096, head_dim 128) at the tiles the kernel chooses for the shape; the
+# _bwd cases compile the gradient through the GQA wrapper (the forward
+# saving its lse, then the backward kernel) at the same two shapes, the
+# cell's with its 8 kv heads.
 @pytest.mark.parametrize("kernel", ["flash_attention", "fused_xent",
-                                    "ssd_intra_chunk", "flash_attention_cell"])
+                                    "ssd_intra_chunk", "flash_attention_cell",
+                                    "flash_attention_bwd",
+                                    "flash_attention_bwd_cell"])
 def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     bf16, f32, i32 = jnp.bfloat16, jnp.float32, jnp.int32
-    if kernel.startswith("flash_attention"):
+    if kernel.startswith("flash_attention_bwd"):
+        B, S, H, K, hd = ((8, 1024, 16, 16, 64)
+                          if kernel == "flash_attention_bwd"
+                          else (2, 4096, 16, 8, 128))
+
+        def loss(q, k, v):
+            return gqa_flash(q, k, v, causal=True, interpret=False).sum(
+                dtype=f32)
+        text = _assert_mosaic(jax.grad(loss, argnums=(0, 1, 2)),
+                              _specs(one_chip, ((B, S, H, hd), bf16),
+                                     ((B, S, K, hd), bf16),
+                                     ((B, S, K, hd), bf16)))
+        assert "flash_attention_bwd" in text
+    elif kernel.startswith("flash_attention"):
         qkv = ((128, 1024, 64) if kernel == "flash_attention"
                else (32, 4096, 128), bf16)
         _assert_mosaic(lambda q, k, v: flash_attention(q, k, v, causal=True,
